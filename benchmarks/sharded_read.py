@@ -1,7 +1,8 @@
 """Sharded zero-host-hop read path vs the host-decide sharded pipeline.
 
-Measures a lookup against the key-sharded DB two ways over the same
-8-virtual-device mesh and entry set:
+Measures a lookup against the key-sharded DB two ways over the same mesh
+(every device JAX sees, or ``--virtual-devices N`` CPU devices) and entry
+set:
 
   * host_decide — the pre-sharded-read shape (``*_host`` methods): one
     banked search dispatch downloads [B, shards*k] merged candidates, then
@@ -26,7 +27,7 @@ Two scenarios, both parity-checked:
 
 Results land in ``BENCH_sharded_read.json``.
 
-Run:  PYTHONPATH=src python benchmarks/sharded_read.py [--smoke]
+Run:  PYTHONPATH=src python benchmarks/sharded_read.py [--smoke] [--virtual-devices 8]
 """
 from __future__ import annotations
 
@@ -36,14 +37,18 @@ import os
 import sys
 import time
 
-# the virtual mesh must exist before jax initializes; set REPRO_BENCH_REAL_MESH
-# to benchmark the actual accelerator topology instead
-if "REPRO_BENCH_REAL_MESH" not in os.environ:
+
+# --virtual-devices acts before JAX starts: the CPU device count is fixed
+# when the backend initializes
+_pre = argparse.ArgumentParser(add_help=False)
+_pre.add_argument("--virtual-devices", type=int, default=0)
+_N_VIRTUAL = _pre.parse_known_args()[0].virtual_devices
+if _N_VIRTUAL:
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
+        + f" --xla_force_host_platform_device_count={_N_VIRTUAL}"
     ).strip()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import numpy as np
 
@@ -275,6 +280,9 @@ def bench_hierarchy(batch_sizes, n_entries, capacity, repeats) -> dict:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="CI-sized run")
+    ap.add_argument("--virtual-devices", type=int, default=0, metavar="N",
+                    help="run on N virtual CPU devices instead of the devices "
+                         "JAX sees (CI: 8)")
     args = ap.parse_args()
 
     if args.smoke:

@@ -9,7 +9,7 @@ token-by-token over SSE — byte-identical to the non-streamed body.
 Run:  PYTHONPATH=src python examples/http_gateway.py
 
 Against a real model instead of the mock:
-      PYTHONPATH=src python -m repro.launch.serve --http 8080
+      PYTHONPATH=src python -m repro.launch.serve --smoke --http 8080
 """
 from repro.core import EnhancedClient, GenerativeCache, MockLLM, NgramHashEmbedder
 from repro.gateway import GatewayClient, serve_in_thread

@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.similarity import SCORE_PRECISION
+
 _KERNEL_METRICS = ("cosine", "dot")  # metrics the Pallas kernel path covers
 _INT32_MIN = np.iinfo(np.int32).min
 # renumber the logical event clock well before int32 saturates (headroom for
@@ -212,17 +214,20 @@ def _lane_scores(db, q, metric: str, prenormalized: bool):
     """db [.., N, D] x q [Q, D] -> scores [.., Q, N] (higher = more similar)."""
     q = q.astype(jnp.float32)
     db = db.astype(jnp.float32)
+    dots = functools.partial(
+        jnp.einsum, "qd,...nd->...qn", precision=SCORE_PRECISION
+    )
     if metric == "cosine":
         if not prenormalized:
             db = _normalize_rows(db)
         q = _normalize_rows(q)
-        return jnp.einsum("qd,...nd->...qn", q, db)
+        return dots(q, db)
     if metric == "dot":
-        return jnp.einsum("qd,...nd->...qn", q, db)
+        return dots(q, db)
     if metric == "euclidean":
         d2 = (
             jnp.sum(q * q, -1)[:, None]
-            - 2 * jnp.einsum("qd,...nd->...qn", q, db)
+            - 2 * dots(q, db)
             + jnp.sum(db * db, -1)[..., None, :]
         )
         return -jnp.sqrt(jnp.maximum(d2, 0.0))
@@ -615,14 +620,14 @@ class StoreBank:
         """Scatter N raw rows into one lane (ONE donated device update that
         also applies the pending insert-counter/lifecycle resets; rows are
         unit-normalized in-jit for cosine lanes). ``pinned=True`` stages the
-        row block through pinned host memory when the backend has it (tier-1
-        promotions overlap their H2D copy with the read dispatch they ride
-        alongside); pageable numpy fallback on CPU."""
+        row block through pinned host memory on the bank's own devices when the
+        platform has it (tier-1 promotions overlap their H2D copy with the
+        read dispatch they ride alongside); pageable numpy on CPU."""
         sel, scatter_idx = prepare_scatter(idxs, np.asarray(rows, np.float32))
         if pinned:
             from repro.kernels.backend import stage_pinned
 
-            sel = stage_pinned(sel)
+            sel = stage_pinned(sel, self.buf)
         cl, ci, ct, cs, cc, ccr, cex = self._drain_pending()
         (
             self.buf, self.valid,

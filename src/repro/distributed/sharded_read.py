@@ -47,7 +47,6 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.read_path import (
@@ -57,6 +56,7 @@ from repro.core.read_path import (
     ReadDecision,
     make_decide,
 )
+from repro.core.similarity import SCORE_PRECISION
 from repro.core.store_bank import (
     StoreBank,
     _lane_scores,
@@ -156,7 +156,7 @@ def _build_sharded_program(
             # make_banked_lookup's kernel body: per-shard MXU dot, local top-k
             dbn = db2 if (metric_j != "cosine" or prenorm_j) else _norm_rows(db2)
             qn = _norm_rows(q) if metric_j == "cosine" else q
-            s = qn @ dbn.T  # [Q, cap_shard]
+            s = jnp.matmul(qn, dbn.T, precision=SCORE_PRECISION)  # [Q, cap_shard]
             if lifecycle:
                 created_l, expires_l, w_l = sh_life[j]
                 c2 = created_l.reshape(cap_shard)
@@ -172,11 +172,11 @@ def _build_sharded_program(
             # contributes only -inf candidates, so after the merge the
             # surviving shards' winners serve the lookup instead of the
             # whole collective failing — degraded, not down
-            s = jnp.where(shard_ok[shard_id(mesh, axes)], s, -jnp.inf)
+            s = jnp.where(shard_ok[shard_id(axes)], s, -jnp.inf)
             ts, ti = jax.lax.top_k(s, min(K, cap_shard))
             # shard-local flat idx -> store-global flat idx, then the tiny
             # [B, k] candidate exchange (ICI first, DCN last)
-            ti = ti + shard_id(mesh, axes) * cap_shard
+            ti = ti + shard_id(axes) * cap_shard
             ts, ti = all_gather_merge_topk(axes, ts, ti, K,
                                            hierarchical=hierarchical)
             level_s[li], level_i[li] = _pad_cols(ts, ti, K)
@@ -208,11 +208,11 @@ def _build_sharded_program(
                 lanes_loc, cap_local = last.shape
                 idxg = idx_all[:, li]
                 within = idxg % cap_local
-                ll = idxg // cap_local - shard_id(mesh, axes) * lanes_loc
+                ll = idxg // cap_local - shard_id(axes) * lanes_loc
                 # a dead shard must not move its counters either (its -inf
                 # candidates never win, but tmask covers probed levels)
                 own = tmask[:, li] & (ll >= 0) & (ll < lanes_loc)
-                own = own & shard_ok[shard_id(mesh, axes)]
+                own = own & shard_ok[shard_id(axes)]
                 llc = jnp.clip(ll, 0, lanes_loc - 1)
                 cnt = cnt.at[llc, within].add(own.astype(jnp.int32))
                 stamp = jnp.where(own, ticks[tick_off + j], jnp.int32(_INT32_MIN))
@@ -231,13 +231,13 @@ def _build_sharded_program(
         (REP2, REP2) if (touch and rep_levels) else (),
         tuple((SH2, SH2) for _ in sh_meta) if touch else (),
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), rep_arr_spec, rep_life_spec,
                   sh_arr_spec, sh_life_spec, P(), counters_spec, P(), P()),
         out_specs=(P(), P(), P(), P(), P(), P(), counters_spec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn, donate_argnums=(9,))
 

@@ -27,8 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
+from repro.core.similarity import SCORE_PRECISION
 from repro.core.store_bank import (
     _TICK_COMPACT_AT,
     StoreBank,
@@ -44,14 +44,14 @@ def _shard_axes(mesh) -> Tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
-def shard_id(mesh, axes: Tuple[str, ...]):
+def shard_id(axes: Tuple[str, ...]):
     """This device's linear shard index over ``axes`` inside a shard_map body
     (row-major over the axis order; matches the lane-axis sharding layout)."""
     sid = jnp.zeros((), jnp.int32)
     mul = 1
     for a in reversed(axes):
         sid = sid + jax.lax.axis_index(a) * mul
-        mul = mul * mesh.shape[a]  # static axis size (jax.lax.axis_size needs jax>=0.5)
+        mul = mul * jax.lax.axis_size(a)
     return sid
 
 
@@ -111,23 +111,23 @@ def make_sharded_lookup(mesh, *, k: int, metric: str = "cosine", hierarchical: b
         if metric == "cosine":
             dbn = _norm_rows(db_l)
             qn = _norm_rows(q)
-        s = qn @ dbn.T  # [Q, cap_local]
+        s = jnp.matmul(qn, dbn.T, precision=SCORE_PRECISION)  # [Q, cap_local]
         s = jnp.where(valid_l[None, :], s, -jnp.inf)
         k_eff = min(k, cap_local)
         top_s, top_i = jax.lax.top_k(s, k_eff)  # local indices
         # translate to global ids, then one shared collective merge
-        top_i = top_i + shard_id(mesh, axes) * cap_local
+        top_i = top_i + shard_id(axes) * cap_local
         return all_gather_merge_topk(axes, top_s, top_i, k,
                                      hierarchical=hierarchical)
 
     db_spec = P(axis_tuple, None)
     valid_spec = P(axis_tuple)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_lookup,
         mesh=mesh,
         in_specs=(db_spec, valid_spec, P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -154,7 +154,10 @@ def make_banked_lookup(
             v2 = valid.reshape(L * capl)
             dbn = db2 if (metric != "cosine" or prenormalized) else _norm_rows(db2)
             qn = _norm_rows(q) if metric == "cosine" else q
-            s = jnp.where(v2[None, :], qn @ dbn.T, -jnp.inf)
+            s = jnp.where(
+                v2[None, :], jnp.matmul(qn, dbn.T, precision=SCORE_PRECISION),
+                -jnp.inf,
+            )
             return jax.lax.top_k(s, min(k, L * capl))
 
         return jax.jit(flat)
@@ -170,20 +173,23 @@ def make_banked_lookup(
         v2 = valid_l.reshape(cap_shard)
         dbn = db2 if (metric != "cosine" or prenormalized) else _norm_rows(db2)
         qn = _norm_rows(q) if metric == "cosine" else q
-        s = jnp.where(v2[None, :], qn @ dbn.T, -jnp.inf)  # [Q, cap_shard]
+        s = jnp.where(
+            v2[None, :], jnp.matmul(qn, dbn.T, precision=SCORE_PRECISION),
+            -jnp.inf,
+        )  # [Q, cap_shard]
         k_eff = min(k, cap_shard)
         top_s, top_i = jax.lax.top_k(s, k_eff)  # shard-local flat indices
         # shard-local flat idx -> bank-global flat idx (lane-major layout)
-        top_i = top_i + shard_id(mesh, axes) * cap_shard
+        top_i = top_i + shard_id(axes) * cap_shard
         return all_gather_merge_topk(axes, top_s, top_i, k,
                                      hierarchical=hierarchical)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_lookup,
         mesh=mesh,
         in_specs=(P(axis_tuple, None, None), P(axis_tuple, None), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -494,7 +500,7 @@ class ShardedVectorStore:
         if pinned:
             from repro.kernels.backend import stage_pinned
 
-            sel_rows = stage_pinned(sel_rows)
+            sel_rows = stage_pinned(sel_rows, self.bank.buf)
         lanes = (sel_idx // self.cap_local).astype(np.int32)
         withins = (sel_idx % self.cap_local).astype(np.int32)
         # the claims' counter + lifecycle resets ride the same donated update

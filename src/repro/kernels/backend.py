@@ -65,39 +65,38 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     return jax.default_backend() == "cpu"
 
 
-# pinned-host staging support: None = not yet probed, else the cached verdict
-_pinned_ok: Optional[bool] = None
+# platforms whose runtimes expose a pinned_host memory space worth staging
+# through (the CPU backend's host memory is the device memory already)
+_PINNED_PLATFORMS = ("tpu", "gpu")
 
 
-def pinned_host_supported() -> bool:
-    """Whether this backend exposes a ``pinned_host`` memory space (TPU/GPU
-    runtimes do; CPU — and older runtimes — don't). Probed once per process
-    with a 1-element transfer; the verdict is cached."""
-    global _pinned_ok
-    if _pinned_ok is None:
-        try:
-            import numpy as np
-            from jax.sharding import SingleDeviceSharding
-
-            dev = jax.devices()[0]
-            sharding = SingleDeviceSharding(dev, memory_kind="pinned_host")
-            jax.device_put(np.zeros(1, np.float32), sharding)
-            _pinned_ok = True
-        except Exception:
-            _pinned_ok = False
-    return _pinned_ok
+def pinned_host_supported(platform: Optional[str] = None) -> bool:
+    """Whether host blocks bound for ``platform`` (default: the default
+    backend) stage through pinned host memory. Chosen from the platform: TPU
+    and GPU runtimes DMA from pinned pages; on CPU there is nothing to gain."""
+    return (platform or jax.default_backend()) in _PINNED_PLATFORMS
 
 
-def stage_pinned(rows):
-    """Stage a host block for an upcoming device scatter through pinned host
-    memory when the backend supports it (the DMA engine can then overlap the
-    H2D copy with compute on TPU/GPU instead of faulting pageable pages);
-    falls back to returning the pageable numpy block unchanged on CPU."""
-    if not pinned_host_supported():
+def _replicated_like(like, memory_kind: Optional[str] = None):
+    """A sharding that replicates a block over the devices ``like`` lives on
+    (its mesh for a named sharding, else its single device)."""
+    from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+    sh = like.sharding
+    if isinstance(sh, NamedSharding):
+        return NamedSharding(sh.mesh, PartitionSpec(), memory_kind=memory_kind)
+    (dev,) = sh.device_set
+    return SingleDeviceSharding(dev, memory_kind=memory_kind)
+
+
+def stage_pinned(rows, like):
+    """Stage a host block for an upcoming device scatter into the array
+    ``like`` (the destination buffer): on TPU/GPU the block is copied into
+    pinned host memory on ``like``'s own devices and DMA'd from there,
+    replicated over them the way the scatter consumes it; on CPU the pageable
+    numpy block is returned unchanged."""
+    platform = next(iter(like.sharding.device_set)).platform
+    if not pinned_host_supported(platform):
         return rows
-    from jax.sharding import SingleDeviceSharding
-
-    dev = jax.devices()[0]
-    return jax.device_put(
-        rows, SingleDeviceSharding(dev, memory_kind="pinned_host")
-    )
+    pinned = jax.device_put(rows, _replicated_like(like, "pinned_host"))
+    return jax.device_put(pinned, _replicated_like(like))

@@ -76,7 +76,7 @@ def _decode_kernel(
     jax.jit, static_argnames=("window", "softcap", "scale", "block_s", "interpret")
 )
 def decode_attention_kernel(
-    q, k, v, lengths, *, window=0, softcap=0.0, scale=None, block_s=256, interpret=True
+    q, k, v, lengths, *, interpret: bool, window=0, softcap=0.0, scale=None, block_s=256
 ):
     B, H, Dh = q.shape
     S, KH = k.shape[1], k.shape[2]

@@ -97,7 +97,7 @@ def _flash_kernel(
 )
 def flash_attention_kernel(
     q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
-    block_q=128, block_k=128, interpret=True,
+    block_q=128, block_k=128, interpret: bool,
 ):
     B, S, H, Dh = q.shape
     KH = k.shape[2]

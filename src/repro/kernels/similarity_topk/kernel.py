@@ -32,6 +32,7 @@ def _topk_block_kernel(db_ref, valid_ref, q_ref, out_s_ref, out_i_ref, *, k: int
         q.astype(jnp.float32),
         db.astype(jnp.float32),
         (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # f32 scores: thresholds sit on them
         preferred_element_type=jnp.float32,
     )  # [Q, block_n]
     s = jnp.where(valid[:, 0][None, :] > 0.5, s, NEG)
@@ -64,6 +65,7 @@ def _topk_lanes_kernel(db_ref, valid_ref, q_ref, out_s_ref, out_i_ref, *, k: int
         q.astype(jnp.float32),
         db.astype(jnp.float32),
         (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # f32 scores: thresholds sit on them
         preferred_element_type=jnp.float32,
     )  # [Q, block_n]
     s = jnp.where(valid[:, 0][None, :] > 0.5, s, NEG)
@@ -81,8 +83,8 @@ def _topk_lanes_kernel(db_ref, valid_ref, q_ref, out_s_ref, out_i_ref, *, k: int
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret", "grid_order"))
-def similarity_topk_lanes_blocks(db, valid_f32, q, *, k: int, block_n: int = 512,
-                                 interpret: bool = True,
+def similarity_topk_lanes_blocks(db, valid_f32, q, *, k: int, interpret: bool,
+                                 block_n: int = 512,
                                  grid_order: str = "lanes_outer"):
     """db [L, N, D], valid_f32 [L, N, 1], q [Q, D] -> per-lane per-block
     candidates (scores [L, nb, Q, k], lane-local idx [L, nb, Q, k]).
@@ -138,7 +140,7 @@ def similarity_topk_lanes_blocks(db, valid_f32, q, *, k: int, block_n: int = 512
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
-def similarity_topk_blocks(db, valid_f32, q, *, k: int, block_n: int = 512, interpret: bool = True):
+def similarity_topk_blocks(db, valid_f32, q, *, k: int, interpret: bool, block_n: int = 512):
     """Returns per-block candidates (scores [nb, Q, k], idx [nb, Q, k])."""
     N, D = db.shape
     Q = q.shape[0]

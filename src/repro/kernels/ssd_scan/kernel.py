@@ -72,7 +72,7 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, y_ref, st_out_ref, st
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan_kernel(x, Bm, Cm, dt, A, D, *, chunk: int = 128, interpret: bool = True):
+def ssd_scan_kernel(x, Bm, Cm, dt, A, D, *, interpret: bool, chunk: int = 128):
     B, S, H, P = x.shape
     N = Bm.shape[-1]
     L = min(chunk, S)

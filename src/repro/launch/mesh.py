@@ -12,17 +12,11 @@ Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
 
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-except ImportError:  # older jax: meshes are implicitly Auto
-
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes)
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -32,6 +32,7 @@ import numpy as np
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs import get_config
 from repro.data.loader import ShardedLoader
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training.train_loop import init_train_state, make_train_step
 
 
@@ -54,6 +55,7 @@ def main(argv=None):
     ap.add_argument("--straggler-factor", type=float, default=3.0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.kernel_interpret is not None:
         from repro.kernels.backend import set_interpret_override

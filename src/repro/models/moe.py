@@ -169,7 +169,6 @@ def moe_ffn(params, cfg, x: jax.Array, capacity_factor: float = 0.0):
 def _moe_ffn_sharded(params, cfg, x: jax.Array, mesh, capacity_factor: float = 0.0):
     """shard_map expert-parallel MoE (see module docstring)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mo = cfg.moe
     B, S, D = x.shape
@@ -223,7 +222,7 @@ def _moe_ffn_sharded(params, cfg, x: jax.Array, mesh, capacity_factor: float = 0
         }
         # shared experts: F sharded over model -> partial sums join the psum
         shared_specs = {"gate": P(None, "model"), "up": P(None, "model"), "down": P("model", None)}
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -235,7 +234,7 @@ def _moe_ffn_sharded(params, cfg, x: jax.Array, mesh, capacity_factor: float = 0
             shared_specs,
         ),
         out_specs=(P(batch_spec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, drop_frac = fn(x, rp, params["w_gate"], params["w_up"], params["w_down"], shared_in)
     return y, {"moe_drop_fraction": drop_frac}

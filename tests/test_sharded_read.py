@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
 
 from repro.core import GenerativeCache, HierarchicalCache  # noqa: E402
 from repro.core.embeddings import NgramHashEmbedder  # noqa: E402
@@ -324,10 +325,13 @@ def test_pinned_staging_cpu_fallback():
     from repro.kernels.backend import pinned_host_supported, stage_pinned
 
     rows = np.arange(2 * DIM, dtype=np.float32).reshape(2, DIM)
-    staged = stage_pinned(rows)
+    dest = jnp.zeros((4, DIM), jnp.float32)
+    staged = stage_pinned(rows, dest)
     np.testing.assert_array_equal(np.asarray(staged), rows)
-    if not pinned_host_supported():  # CPU: pageable block passes through
-        assert staged is rows
+    # chosen from the platform: TPU/GPU stage through pinned pages, CPU not
+    assert pinned_host_supported("tpu") and pinned_host_supported("gpu")
+    assert not pinned_host_supported("cpu")
+    assert staged is rows  # CPU: the pageable block passes through
 
 
 def test_shard_mask_degrades_to_survivors():
