@@ -55,9 +55,7 @@ class GenerativeCache(SemanticCache):
     def _generative_lookup(
         self, query: str, vec: np.ndarray, t_s: float, t_start: float
     ) -> CacheResult:
-        t0 = time.perf_counter()
         matches = self.store.search(vec, k=self.max_sources)
-        self.stats.search_time_s += time.perf_counter() - t0
         X = [(s, e) for s, e in matches if s > self.t_single]
         combined = float(sum(s for s, _ in X))
         best = matches[0][0] if matches else -1.0
@@ -97,9 +95,7 @@ class GenerativeCache(SemanticCache):
             return self._generative_lookup(query, vec, t_s, t_start)
 
         # secondary: regular semantic lookup first
-        t0 = time.perf_counter()
         matches = self.store.search(vec, k=1)
-        self.stats.search_time_s += time.perf_counter() - t0
         if matches and matches[0][0] > t_s:
             s, e = matches[0]
             self.stats.hits += 1

@@ -35,6 +35,7 @@ import time
 from typing import List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.generative_cache import GenerativeCache
 from repro.core.semantic_cache import CacheResult, SemanticCache
@@ -353,7 +354,6 @@ class HierarchicalCache:
                 read_path.level_spec(c, ks[li]) for li, (_, c) in enumerate(levels)
             ]
             if all(sp is not None for sp in specs):
-                t0s = time.perf_counter()
                 if srb is not None:
                     router = (
                         self.router(queries, contexts)
@@ -367,17 +367,12 @@ class HierarchicalCache:
                     dec = read_path.fused_read(
                         bank, self.l1.embedder, queries, thr, specs, vecs=vecs
                     )
-                # the program is indivisible, so search_time_s absorbs the
-                # whole fused wall time (embed leg included) split evenly —
-                # slightly broader than the host tiers' search-only share
-                share = (time.perf_counter() - t0s) / len(levels)
-                for _, c in levels:
-                    c.stats.search_time_s += share
         if dec is not None:
             vecs = dec.vecs
-            out, promotions, l2_copies, deferred = self._materialize_fused(
-                queries, contexts, thr, levels, ks, dec
-            )
+            with TraceAnnotation("read.materialize"):
+                out, promotions, l2_copies, deferred = self._materialize_fused(
+                    queries, contexts, thr, levels, ks, dec
+                )
         else:
             if vecs is None:
                 vecs = self.l1.embed_batch(list(queries))
@@ -503,9 +498,7 @@ class HierarchicalCache:
             # [L, cap, D] x [B, D] top-k dispatch; per-level decision rules
             # (and the L1-beats-L2-beats-peers walk below) run host-side on
             # the returned scores — no extra dispatches
-            t0s = time.perf_counter()
             s_all, i_all = bank.search_lanes(vecs, max(ks))  # [B, L, k_fused]
-            search_share = (time.perf_counter() - t0s) / len(levels)
             for li, (_, cache) in enumerate(levels):
                 # touch=False equivalent: the join skips the recency bump;
                 # counters move below, only on levels the walk would probe
@@ -514,7 +507,6 @@ class HierarchicalCache:
                 )
                 if ks[li] < max(ks):  # this level's own k, like its solo search
                     matches = [m[: ks[li]] for m in matches]
-                cache.stats.search_time_s += search_share
                 results, _ = cache._decide_batch(queries, thr[:, li], matches, lazy_synth=True)
                 level_results.append(results)
                 level_matches.append(matches)

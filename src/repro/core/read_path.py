@@ -40,6 +40,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.store_bank import StoreBank, fused_search_body, pad_to_bucket
 
@@ -197,42 +198,52 @@ def _build_program(forward, specs: Tuple[LevelSpec, ...], K: int,
         last = last.at[lanes3, idx].max(stamp)
         return s, idx, winner, hit, generative, last, cnt
 
+    # the stages' named scopes give their device ops stable names in a
+    # profile; the module and kernel names are left as they are
     if not lifecycle:
-        # TTL-free deployments compile the exact PR-5 program: same signature,
-        # same donation, byte-identical trace
+        # TTL-free deployments compile a program without the lifecycle
+        # inputs: no expiry mask, no staleness rescore
         def program(embed_args, thresholds, qmask, buf, valid, last, cnt, tick):
-            q = forward(*embed_args)  # [B, D] — embeds never leave the device
-            s, idx = search(q, buf, valid)
-            s, idx, winner, hit, generative, last, cnt = decide_and_touch(
-                s, idx, thresholds, qmask, last, cnt, tick
-            )
+            with jax.named_scope("encoder"):
+                q = forward(*embed_args)  # [B, D] — embeds never leave the device
+            with jax.named_scope("search"):
+                s, idx = search(q, buf, valid)
+            with jax.named_scope("decide"):
+                s, idx, winner, hit, generative, last, cnt = decide_and_touch(
+                    s, idx, thresholds, qmask, last, cnt, tick
+                )
             return q, s, idx, winner, hit, generative, last, cnt
 
         return jax.jit(program, donate_argnums=(5, 6))
 
     def program_lc(embed_args, thresholds, qmask, buf, valid, created,
                    expires, w, now, last, cnt, tick):
-        q = forward(*embed_args)
-        # expiry mask INSIDE the decide stage: a dead row is invalid for this
-        # dispatch, so it can never surface as a candidate, let alone win
-        s, idx = search(q, buf, valid & (expires > now))
-        finite = s > jnp.float32(_NEG_FINITE)
-        lanes3 = jnp.broadcast_to(jnp.arange(L)[None, :, None], s.shape)
-        c = created[lanes3, idx]
-        e = expires[lanes3, idx]
-        # staleness-aware scoring: an aging entry must beat a higher bar —
-        # w[lane] * clip(age/ttl, 0, 1) comes off its similarity
-        frac = jnp.clip((now - c) / jnp.maximum(e - c, 1e-6), 0.0, 1.0)
-        pen = jnp.where(
-            finite & jnp.isfinite(e), w[None, :, None] * frac, 0.0
-        )
-        s = s - pen
-        # re-establish descending order (decide assumes best-first candidates)
-        s, order = jax.lax.top_k(s, K)
-        idx = jnp.take_along_axis(idx, order, axis=-1)
-        s, idx, winner, hit, generative, last, cnt = decide_and_touch(
-            s, idx, thresholds, qmask, last, cnt, tick
-        )
+        with jax.named_scope("encoder"):
+            q = forward(*embed_args)
+        with jax.named_scope("search"):
+            # expiry mask INSIDE the decide stage: a dead row is invalid for
+            # this dispatch, so it can never surface as a candidate, let
+            # alone win
+            s, idx = search(q, buf, valid & (expires > now))
+            finite = s > jnp.float32(_NEG_FINITE)
+            lanes3 = jnp.broadcast_to(jnp.arange(L)[None, :, None], s.shape)
+            c = created[lanes3, idx]
+            e = expires[lanes3, idx]
+            # staleness-aware scoring: an aging entry must beat a higher bar —
+            # w[lane] * clip(age/ttl, 0, 1) comes off its similarity
+            frac = jnp.clip((now - c) / jnp.maximum(e - c, 1e-6), 0.0, 1.0)
+            pen = jnp.where(
+                finite & jnp.isfinite(e), w[None, :, None] * frac, 0.0
+            )
+            s = s - pen
+            # re-establish descending order (decide assumes best-first
+            # candidates)
+            s, order = jax.lax.top_k(s, K)
+            idx = jnp.take_along_axis(idx, order, axis=-1)
+        with jax.named_scope("decide"):
+            s, idx, winner, hit, generative, last, cnt = decide_and_touch(
+                s, idx, thresholds, qmask, last, cnt, tick
+            )
         return q, s, idx, winner, hit, generative, last, cnt
 
     return jax.jit(program_lc, donate_argnums=(9, 10))
@@ -248,7 +259,11 @@ def fused_read(
 ) -> ReadDecision:
     """Run one fused read over a bank: ONE device dispatch end-to-end,
     including the eviction-counter touches. ``vecs`` short-circuits the
-    embed stage (callers that already hold embeddings upload them once)."""
+    embed stage (callers that already hold embeddings upload them once).
+
+    Profiler spans: ``read.tokenize`` (the host's input prep),
+    ``read.dispatch`` (enqueueing the program) and ``read.fetch`` (the host
+    blocked on the device until the decision tensors arrive)."""
     from repro.core.embeddings import _identity_forward
     from repro.kernels.similarity_topk import ops as st_ops
 
@@ -256,44 +271,47 @@ def fused_read(
     specs = tuple(specs)
     L = len(specs)
     K = max(s.k for s in specs)
-    if vecs is not None:
-        v, _ = pad_to_bucket(np.asarray(vecs, np.float32).reshape(n, bank.dim))
-        args, B, forward = (v,), v.shape[0], _identity_forward
-    else:
-        prepare, forward = embedder.fused_forward()
-        args, n_prep, B = prepare(list(texts))
-        assert n_prep == n
-    qmask = np.arange(B) < n
-    thr = np.full((B, L), np.inf, np.float32)
-    thr[:n] = np.asarray(thresholds, np.float32).reshape(n, L)
+    with TraceAnnotation("read.tokenize"):
+        if vecs is not None:
+            v, _ = pad_to_bucket(np.asarray(vecs, np.float32).reshape(n, bank.dim))
+            args, B, forward = (v,), v.shape[0], _identity_forward
+        else:
+            prepare, forward = embedder.fused_forward()
+            args, n_prep, B = prepare(list(texts))
+            assert n_prep == n
+        qmask = np.arange(B) < n
+        thr = np.full((B, L), np.inf, np.float32)
+        thr[:n] = np.asarray(thresholds, np.float32).reshape(n, L)
 
-    bank.flush_pending()
-    use_pallas = bank.use_pallas and bank._kernel_ok()
-    lifecycle = bank.lifecycle_active()
-    program = _build_program(
-        forward, specs, K, bank.metrics, bank.prenorm, use_pallas,
-        bank._resolved_interpret(), st_ops.default_block_n(),
-        st_ops.default_grid_order(), lifecycle,
-    )
-    tick = bank.next_tick()
-    bank.dispatches += 1
-    if use_pallas:
-        st_ops.record_dispatch()
-    if lifecycle:
-        q, s, idx, winner, hit, gen, last, cnt = program(
-            args, thr, qmask, bank.buf, bank.valid,
-            bank.d_created, bank.d_expires, bank.d_staleness(),
-            np.float32(bank.rel_now()),
-            bank.d_last_access, bank.d_access_count, np.int32(tick),
+    with TraceAnnotation("read.dispatch"):
+        bank.flush_pending()
+        use_pallas = bank.use_pallas and bank._kernel_ok()
+        lifecycle = bank.lifecycle_active()
+        program = _build_program(
+            forward, specs, K, bank.metrics, bank.prenorm, use_pallas,
+            bank._resolved_interpret(), st_ops.default_block_n(),
+            st_ops.default_grid_order(), lifecycle,
         )
-    else:
-        q, s, idx, winner, hit, gen, last, cnt = program(
-            args, thr, qmask, bank.buf, bank.valid,
-            bank.d_last_access, bank.d_access_count, np.int32(tick),
-        )
-    bank.adopt_fused_counters(last, cnt)
+        tick = bank.next_tick()
+        bank.dispatches += 1
+        if use_pallas:
+            st_ops.record_dispatch()
+        if lifecycle:
+            q, s, idx, winner, hit, gen, last, cnt = program(
+                args, thr, qmask, bank.buf, bank.valid,
+                bank.d_created, bank.d_expires, bank.d_staleness(),
+                np.float32(bank.rel_now()),
+                bank.d_last_access, bank.d_access_count, np.int32(tick),
+            )
+        else:
+            q, s, idx, winner, hit, gen, last, cnt = program(
+                args, thr, qmask, bank.buf, bank.valid,
+                bank.d_last_access, bank.d_access_count, np.int32(tick),
+            )
+        bank.adopt_fused_counters(last, cnt)
     # ONE host fetch for all decision tensors (the counters stay on device)
-    q, s, idx, winner, hit, gen = jax.device_get((q, s, idx, winner, hit, gen))
+    with TraceAnnotation("read.fetch"):
+        q, s, idx, winner, hit, gen = jax.device_get((q, s, idx, winner, hit, gen))
     return ReadDecision(q[:n], s[:n], idx[:n], winner[:n], hit[:n], gen[:n])
 
 

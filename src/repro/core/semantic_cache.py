@@ -13,6 +13,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.embeddings import EmbeddingModel
 from repro.core.vector_store import Entry, InMemoryVectorStore
@@ -39,8 +40,6 @@ class CacheStats:
     tier1_hits: int = 0  # tier-0 misses served from the host-RAM tier
     stale_hits: int = 0  # expired entries served stale-if-error (backends down)
     adds: int = 0
-    embed_time_s: float = 0.0
-    search_time_s: float = 0.0
     add_time_s: float = 0.0
 
     @property
@@ -81,33 +80,24 @@ class SemanticCache:
     # -- embedding ------------------------------------------------------------
 
     def embed(self, query: str) -> np.ndarray:
-        t0 = time.perf_counter()
-        v = self.embedder.embed_one(query)
-        self.stats.embed_time_s += time.perf_counter() - t0
-        return v
+        return self.embedder.embed_one(query)
 
     def embed_batch(self, queries: List[str]) -> np.ndarray:
         """Embed a request batch in one model forward ([B, L] tokens)."""
-        t0 = time.perf_counter()
-        v = self.embedder.embed_batch(list(queries))
-        self.stats.embed_time_s += time.perf_counter() - t0
-        return v
+        return self.embedder.embed_batch(list(queries))
 
     # -- candidate search (shared with the hierarchy) ----------------------------
 
     def search_candidates(
         self, vecs: np.ndarray, k: int, touch: bool = True
     ) -> List[List[Tuple[float, Entry]]]:
-        """One timed store search for the whole batch. ``touch=False`` defers
+        """One store search for the whole batch. ``touch=False`` defers
         LRU/LFU bookkeeping to the caller — the hierarchy probes every level
         speculatively and bumps only levels a sequential walk would reach."""
-        t0 = time.perf_counter()
         try:
-            matches = self.store.search_batch(np.asarray(vecs), k=k, touch=touch)
+            return self.store.search_batch(np.asarray(vecs), k=k, touch=touch)
         except TypeError:  # store without deferred-bookkeeping support
-            matches = self.store.search_batch(np.asarray(vecs), k=k)
-        self.stats.search_time_s += time.perf_counter() - t0
-        return matches
+            return self.store.search_batch(np.asarray(vecs), k=k)
 
     def touch(self, keys) -> None:
         """Apply deferred recency/frequency bookkeeping (no-op for stores
@@ -270,9 +260,7 @@ class SemanticCache:
         t_s = self.effective_threshold(query, context)
         if vec is None:
             vec = self.embed(query)
-        t0 = time.perf_counter()
         matches = self.store.search(vec, k=1)
-        self.stats.search_time_s += time.perf_counter() - t0
         if matches and matches[0][0] > t_s:
             score, entry = matches[0]
             self.stats.hits += 1
@@ -313,12 +301,10 @@ class SemanticCache:
         spec = read_path.level_spec(self, k)
         if spec is None:
             return None, 0
-        t0 = time.perf_counter()
         dec = read_path.fused_read(
             store._bank, self.embedder, queries,
             np.asarray(thresholds, np.float32).reshape(-1, 1), (spec,), vecs=vecs,
         )
-        self.stats.search_time_s += time.perf_counter() - t0
         return dec, k
 
     def lookup_batch(
@@ -346,31 +332,33 @@ class SemanticCache:
             return ([], empty) if return_vecs else []
         contexts = list(contexts) if contexts is not None else [None] * n
         self.stats.lookups += n
-        thresholds = np.asarray(
-            [self.effective_threshold(q, c) for q, c in zip(queries, contexts)]
-        )
+        with TraceAnnotation("read.thresholds"):
+            thresholds = np.asarray(
+                [self.effective_threshold(q, c) for q, c in zip(queries, contexts)]
+            )
         dec, k = self._fused_read_decision(queries, thresholds, vecs)
         if dec is not None:
-            matches = [
-                m[:k]
-                for m in self.store.join_candidates(
-                    dec.scores[:, 0], dec.idx[:, 0], touch=False
+            with TraceAnnotation("read.join"):
+                matches = [
+                    m[:k]
+                    for m in self.store.join_candidates(
+                        dec.scores[:, 0], dec.idx[:, 0], touch=False
+                    )
+                ]
+            with TraceAnnotation("read.materialize"):
+                results, to_insert = self._materialize_batch(
+                    queries, thresholds, matches, dec.hit[:, 0], dec.generative[:, 0]
                 )
-            ]
-            results, to_insert = self._materialize_batch(
-                queries, thresholds, matches, dec.hit[:, 0], dec.generative[:, 0]
-            )
             vecs = dec.vecs
         else:
             if vecs is None:
                 vecs = self.embed_batch(list(queries))
-            t0 = time.perf_counter()
             matches = self.store.search_batch(np.asarray(vecs), k=self._solo_k())
-            self.stats.search_time_s += time.perf_counter() - t0
             results, to_insert = self._decide_batch(queries, thresholds, matches)
         misses = [i for i, r in enumerate(results) if not r.hit]
         if misses:
-            promoted = self.consult_tier1(queries, vecs, thresholds, misses)
+            with TraceAnnotation("read.tier1"):
+                promoted = self.consult_tier1(queries, vecs, thresholds, misses)
             for i, r in promoted.items():
                 results[i] = r
         per_query_s = (time.perf_counter() - t_start) / n
@@ -378,12 +366,13 @@ class SemanticCache:
             r.latency_s = per_query_s
         if to_insert:
             # whole synthesized set lands in one add_batch scatter
-            self.insert_batch(
-                [queries[i] for i, _ in to_insert],
-                [r for _, r in to_insert],
-                metas=[{"generative": True}] * len(to_insert),
-                vecs=np.stack([np.asarray(vecs[i]) for i, _ in to_insert]),
-            )
+            with TraceAnnotation("read.insert"):
+                self.insert_batch(
+                    [queries[i] for i, _ in to_insert],
+                    [r for _, r in to_insert],
+                    metas=[{"generative": True}] * len(to_insert),
+                    vecs=np.stack([np.asarray(vecs[i]) for i, _ in to_insert]),
+                )
         return (results, np.asarray(vecs)) if return_vecs else results
 
     def _decide_batch(
@@ -567,7 +556,6 @@ class GPTCacheLike:
         if vec is None:
             vec = self.embedder.embed_one(query)
         v = np.asarray(vec, np.float64)
-        t0 = time.perf_counter()
         best_s, best_e = -1.0, None
         for row_vec, entry in self.rows:  # per-row scalar evaluation
             num = 0.0
@@ -580,7 +568,6 @@ class GPTCacheLike:
             s = num / max(np.sqrt(na) * np.sqrt(nb), 1e-9)
             if s > best_s:
                 best_s, best_e = s, entry
-        self.stats.search_time_s += time.perf_counter() - t0
         if best_e is not None and best_s > self.threshold:
             self.stats.hits += 1
             return CacheResult(True, best_e.response, best_s, best_s, False,

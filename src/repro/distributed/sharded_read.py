@@ -47,6 +47,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.core.read_path import (
@@ -113,112 +114,115 @@ def _build_sharded_program(
 
     def body(embed_args, thr, qmask, router, rep_arrays, rep_life, sh_arrays,
              sh_life, now, counters, ticks, shard_ok):
-        q = forward(*embed_args)  # replicated: embeds never leave the device
-        level_s: List = [None] * L
-        level_i: List = [None] * L
-        if rep_levels:
-            buf, valid = rep_arrays
-            cap = buf.shape[1]
-            if lifecycle:
-                created, expires, w = rep_life
-                # expiry mask + staleness penalty PRE-top-k (module docstring)
-                valid_eff = valid & (expires > now)
-                frac = jnp.clip(
-                    (now - created) / jnp.maximum(expires - created, 1e-6),
-                    0.0, 1.0,
-                )
-                pen = jnp.where(jnp.isfinite(expires), w[:, None] * frac, 0.0)
-            else:
-                valid_eff, pen = valid, None
-            # fused_search_body's scoring with the optional pre-top-k penalty
-            if len(set(rep_metrics)) == 1:
-                s = _lane_scores(buf, q, rep_metrics[0], all(rep_prenorm))
-            else:
-                s = jnp.stack([
-                    _lane_scores(buf[r], q, rep_metrics[r], rep_prenorm[r])
-                    for r in range(len(rep_metrics))
-                ])
-            if pen is not None:
-                s = s - pen[:, None, :]
-            s = jnp.where(valid_eff[:, None, :], s, -jnp.inf)  # [Lr, Q, cap]
-            ts, ti = jax.lax.top_k(s, min(K, cap))
-            ts, ti = ts.transpose(1, 0, 2), ti.transpose(1, 0, 2)
-            ts, ti = _pad_cols(ts, ti, K)
-            for li, j in rep_levels:
-                level_s[li], level_i[li] = ts[:, j], ti[:, j]
-        for li, j in sh_levels:
-            db_l, valid_l = sh_arrays[j]
-            lanes_loc, cap_local, dim = db_l.shape
-            cap_shard = lanes_loc * cap_local
-            metric_j, prenorm_j = sh_meta[j]
-            db2 = db_l.reshape(cap_shard, dim)
-            v2 = valid_l.reshape(cap_shard)
-            # make_banked_lookup's kernel body: per-shard MXU dot, local top-k
-            dbn = db2 if (metric_j != "cosine" or prenorm_j) else _norm_rows(db2)
-            qn = _norm_rows(q) if metric_j == "cosine" else q
-            s = jnp.matmul(qn, dbn.T, precision=SCORE_PRECISION)  # [Q, cap_shard]
-            if lifecycle:
-                created_l, expires_l, w_l = sh_life[j]
-                c2 = created_l.reshape(cap_shard)
-                e2 = expires_l.reshape(cap_shard)
-                w2 = jnp.repeat(w_l, cap_local)
-                v2 = v2 & (e2 > now)
-                frac = jnp.clip(
-                    (now - c2) / jnp.maximum(e2 - c2, 1e-6), 0.0, 1.0
-                )
-                s = s - jnp.where(jnp.isfinite(e2), w2 * frac, 0.0)[None, :]
-            s = jnp.where(v2[None, :], s, -jnp.inf)
-            # shard-availability mask (resilience): a shard marked dead
-            # contributes only -inf candidates, so after the merge the
-            # surviving shards' winners serve the lookup instead of the
-            # whole collective failing — degraded, not down
-            s = jnp.where(shard_ok[shard_id(axes)], s, -jnp.inf)
-            ts, ti = jax.lax.top_k(s, min(K, cap_shard))
-            # shard-local flat idx -> store-global flat idx, then the tiny
-            # [B, k] candidate exchange (ICI first, DCN last)
-            ti = ti + shard_id(axes) * cap_shard
-            ts, ti = all_gather_merge_topk(axes, ts, ti, K,
-                                           hierarchical=hierarchical)
-            level_s[li], level_i[li] = _pad_cols(ts, ti, K)
-        s_all = jnp.stack(level_s, 1)  # [B, L, K]
-        idx_all = jnp.stack(level_i, 1)
-        # device-side router: an invisible lane's candidates can neither win
-        # nor be touched (the decide masks key off finite scores)
-        s_all = jnp.where(router[:, :, None], s_all, -jnp.inf)
-        winner, hit, generative, tmask = decide(s_all, thr, qmask)
-        rep_c, sh_c = counters
-        if touch and rep_levels:
-            # replicated counters: every device applies the identical full
-            # scatter, so the arrays stay replicated without a collective
-            last, cnt = rep_c
-            idx_r = jnp.stack([idx_all[:, li] for li, _ in rep_levels], 1)
-            tm_r = jnp.stack([tmask[:, li] for li, _ in rep_levels], 1)
-            lane_ids = jnp.asarray([j for _, j in rep_levels], jnp.int32)
-            lanes3 = jnp.broadcast_to(lane_ids[None, :, None], idx_r.shape)
-            cnt = cnt.at[lanes3, idx_r].add(tm_r.astype(jnp.int32))
-            stamp = jnp.where(tm_r, ticks[0], jnp.int32(_INT32_MIN))
-            last = last.at[lanes3, idx_r].max(stamp)
-            rep_c = (last, cnt)
-        if touch and sh_levels:
-            out_sh = []
+        with jax.named_scope("encoder"):
+            q = forward(*embed_args)  # replicated: embeds never leave the device
+        with jax.named_scope("search"):
+            level_s: List = [None] * L
+            level_i: List = [None] * L
+            if rep_levels:
+                buf, valid = rep_arrays
+                cap = buf.shape[1]
+                if lifecycle:
+                    created, expires, w = rep_life
+                    # expiry mask + staleness penalty PRE-top-k (module docstring)
+                    valid_eff = valid & (expires > now)
+                    frac = jnp.clip(
+                        (now - created) / jnp.maximum(expires - created, 1e-6),
+                        0.0, 1.0,
+                    )
+                    pen = jnp.where(jnp.isfinite(expires), w[:, None] * frac, 0.0)
+                else:
+                    valid_eff, pen = valid, None
+                # fused_search_body's scoring with the optional pre-top-k penalty
+                if len(set(rep_metrics)) == 1:
+                    s = _lane_scores(buf, q, rep_metrics[0], all(rep_prenorm))
+                else:
+                    s = jnp.stack([
+                        _lane_scores(buf[r], q, rep_metrics[r], rep_prenorm[r])
+                        for r in range(len(rep_metrics))
+                    ])
+                if pen is not None:
+                    s = s - pen[:, None, :]
+                s = jnp.where(valid_eff[:, None, :], s, -jnp.inf)  # [Lr, Q, cap]
+                ts, ti = jax.lax.top_k(s, min(K, cap))
+                ts, ti = ts.transpose(1, 0, 2), ti.transpose(1, 0, 2)
+                ts, ti = _pad_cols(ts, ti, K)
+                for li, j in rep_levels:
+                    level_s[li], level_i[li] = ts[:, j], ti[:, j]
             for li, j in sh_levels:
-                # ownership-masked local scatter: each shard bumps only the
-                # slots it owns — no cross-device counter traffic at all
-                last, cnt = sh_c[j]
-                lanes_loc, cap_local = last.shape
-                idxg = idx_all[:, li]
-                within = idxg % cap_local
-                ll = idxg // cap_local - shard_id(axes) * lanes_loc
-                # a dead shard must not move its counters either (its -inf
-                # candidates never win, but tmask covers probed levels)
-                own = tmask[:, li] & (ll >= 0) & (ll < lanes_loc)
-                own = own & shard_ok[shard_id(axes)]
-                llc = jnp.clip(ll, 0, lanes_loc - 1)
-                cnt = cnt.at[llc, within].add(own.astype(jnp.int32))
-                stamp = jnp.where(own, ticks[tick_off + j], jnp.int32(_INT32_MIN))
-                last = last.at[llc, within].max(stamp)
-                out_sh.append((last, cnt))
-            sh_c = tuple(out_sh)
+                db_l, valid_l = sh_arrays[j]
+                lanes_loc, cap_local, dim = db_l.shape
+                cap_shard = lanes_loc * cap_local
+                metric_j, prenorm_j = sh_meta[j]
+                db2 = db_l.reshape(cap_shard, dim)
+                v2 = valid_l.reshape(cap_shard)
+                # make_banked_lookup's kernel body: per-shard MXU dot, local top-k
+                dbn = db2 if (metric_j != "cosine" or prenorm_j) else _norm_rows(db2)
+                qn = _norm_rows(q) if metric_j == "cosine" else q
+                s = jnp.matmul(qn, dbn.T, precision=SCORE_PRECISION)  # [Q, cap_shard]
+                if lifecycle:
+                    created_l, expires_l, w_l = sh_life[j]
+                    c2 = created_l.reshape(cap_shard)
+                    e2 = expires_l.reshape(cap_shard)
+                    w2 = jnp.repeat(w_l, cap_local)
+                    v2 = v2 & (e2 > now)
+                    frac = jnp.clip(
+                        (now - c2) / jnp.maximum(e2 - c2, 1e-6), 0.0, 1.0
+                    )
+                    s = s - jnp.where(jnp.isfinite(e2), w2 * frac, 0.0)[None, :]
+                s = jnp.where(v2[None, :], s, -jnp.inf)
+                # shard-availability mask (resilience): a shard marked dead
+                # contributes only -inf candidates, so after the merge the
+                # surviving shards' winners serve the lookup instead of the
+                # whole collective failing — degraded, not down
+                s = jnp.where(shard_ok[shard_id(axes)], s, -jnp.inf)
+                ts, ti = jax.lax.top_k(s, min(K, cap_shard))
+                # shard-local flat idx -> store-global flat idx, then the tiny
+                # [B, k] candidate exchange (ICI first, DCN last)
+                ti = ti + shard_id(axes) * cap_shard
+                ts, ti = all_gather_merge_topk(axes, ts, ti, K,
+                                               hierarchical=hierarchical)
+                level_s[li], level_i[li] = _pad_cols(ts, ti, K)
+            s_all = jnp.stack(level_s, 1)  # [B, L, K]
+            idx_all = jnp.stack(level_i, 1)
+            # device-side router: an invisible lane's candidates can neither win
+            # nor be touched (the decide masks key off finite scores)
+            s_all = jnp.where(router[:, :, None], s_all, -jnp.inf)
+        with jax.named_scope("decide"):
+            winner, hit, generative, tmask = decide(s_all, thr, qmask)
+            rep_c, sh_c = counters
+            if touch and rep_levels:
+                # replicated counters: every device applies the identical full
+                # scatter, so the arrays stay replicated without a collective
+                last, cnt = rep_c
+                idx_r = jnp.stack([idx_all[:, li] for li, _ in rep_levels], 1)
+                tm_r = jnp.stack([tmask[:, li] for li, _ in rep_levels], 1)
+                lane_ids = jnp.asarray([j for _, j in rep_levels], jnp.int32)
+                lanes3 = jnp.broadcast_to(lane_ids[None, :, None], idx_r.shape)
+                cnt = cnt.at[lanes3, idx_r].add(tm_r.astype(jnp.int32))
+                stamp = jnp.where(tm_r, ticks[0], jnp.int32(_INT32_MIN))
+                last = last.at[lanes3, idx_r].max(stamp)
+                rep_c = (last, cnt)
+            if touch and sh_levels:
+                out_sh = []
+                for li, j in sh_levels:
+                    # ownership-masked local scatter: each shard bumps only the
+                    # slots it owns — no cross-device counter traffic at all
+                    last, cnt = sh_c[j]
+                    lanes_loc, cap_local = last.shape
+                    idxg = idx_all[:, li]
+                    within = idxg % cap_local
+                    ll = idxg // cap_local - shard_id(axes) * lanes_loc
+                    # a dead shard must not move its counters either (its -inf
+                    # candidates never win, but tmask covers probed levels)
+                    own = tmask[:, li] & (ll >= 0) & (ll < lanes_loc)
+                    own = own & shard_ok[shard_id(axes)]
+                    llc = jnp.clip(ll, 0, lanes_loc - 1)
+                    cnt = cnt.at[llc, within].add(own.astype(jnp.int32))
+                    stamp = jnp.where(own, ticks[tick_off + j], jnp.int32(_INT32_MIN))
+                    last = last.at[llc, within].max(stamp)
+                    out_sh.append((last, cnt))
+                sh_c = tuple(out_sh)
         return q, s_all, idx_all, winner, hit, generative, (rep_c, sh_c)
 
     REP3, REP2, REP1 = P(None, None, None), P(None, None), P(None)
@@ -357,91 +361,96 @@ class ShardedReadBank:
         score -inf inside the program and their counters stay untouched, so
         a lookup degrades to the surviving shards' winners instead of the
         whole collective failing — the read-path leg of the resilience
-        degradation ladder."""
+        degradation ladder.
+
+        Opens the profiler spans of ``read_path.fused_read``:
+        ``read.tokenize``, ``read.dispatch`` and ``read.fetch``."""
         from repro.core.embeddings import _identity_forward
 
         n = len(texts)
         specs = tuple(specs)
         L = len(specs)
         K = max(sp.k for sp in specs)
-        if vecs is not None:
-            v, _ = pad_to_bucket(np.asarray(vecs, np.float32).reshape(n, self.dim))
-            args, B, forward = (v,), v.shape[0], _identity_forward
-        else:
-            prepare, forward = embedder.fused_forward()
-            args, n_prep, B = prepare(list(texts))
-            assert n_prep == n
-        qmask = np.arange(B) < n
-        thr = np.full((B, L), np.inf, np.float32)
-        thr[:n] = np.asarray(thresholds, np.float32).reshape(n, L)
-        rmask = np.ones((B, L), bool)
-        if router is not None:
-            rmask[:n] = np.asarray(router, bool).reshape(n, L)
-
-        banks = self.banks()
-        for b in banks:
-            b.flush_pending()
-        lifecycle = self.lifecycle_active()
-        rb = self.rep_bank
-        rep_meta = (rb.metrics, rb.prenorm) if rb is not None else None
-        sh_meta = tuple(
-            (s.metric, s.bank.prenormalized) for s in self.sh_stores
-        )
-        program = _build_sharded_program(
-            forward, self.mesh, self.layout, specs, K, rep_meta, sh_meta,
-            lifecycle, touch,
-        )
-        rep_arrays = (rb.buf, rb.valid) if rb is not None else ()
-        rep_life = (
-            (rb.d_created, rb.d_expires, rb.d_staleness())
-            if (rb is not None and lifecycle) else ()
-        )
-        sh_arrays = tuple((s.bank.buf, s.bank.valid) for s in self.sh_stores)
-        sh_life = tuple(
-            (s.bank.d_created, s.bank.d_expires, s.bank.d_staleness())
-            for s in self.sh_stores
-        ) if lifecycle else ()
-        if touch:
-            ticks = tuple(np.int32(b.next_tick()) for b in banks)
-            counters = (
-                (rb.d_last_access, rb.d_access_count) if rb is not None else (),
-                tuple(
-                    (s.bank.d_last_access, s.bank.d_access_count)
-                    for s in self.sh_stores
-                ),
+        with TraceAnnotation("read.tokenize"):
+            if vecs is not None:
+                v, _ = pad_to_bucket(np.asarray(vecs, np.float32).reshape(n, self.dim))
+                args, B, forward = (v,), v.shape[0], _identity_forward
+            else:
+                prepare, forward = embedder.fused_forward()
+                args, n_prep, B = prepare(list(texts))
+                assert n_prep == n
+            qmask = np.arange(B) < n
+            thr = np.full((B, L), np.inf, np.float32)
+            thr[:n] = np.asarray(thresholds, np.float32).reshape(n, L)
+            rmask = np.ones((B, L), bool)
+            if router is not None:
+                rmask[:n] = np.asarray(router, bool).reshape(n, L)
+        with TraceAnnotation("read.dispatch"):
+            banks = self.banks()
+            for b in banks:
+                b.flush_pending()
+            lifecycle = self.lifecycle_active()
+            rb = self.rep_bank
+            rep_meta = (rb.metrics, rb.prenorm) if rb is not None else None
+            sh_meta = tuple(
+                (s.metric, s.bank.prenormalized) for s in self.sh_stores
             )
-        else:
-            ticks = ()
-            counters = ((), ())
-        if shard_mask is None:
-            shard_ok = np.ones(self.n_shards, bool)
-        else:
-            shard_ok = np.asarray(shard_mask, bool).reshape(self.n_shards)
-            if not shard_ok.any():
-                raise ValueError("shard_mask marks every shard dead")
-            if not shard_ok.all():
-                self.degraded_reads += 1
-        self.dispatches += 1
-        q, s, idx, winner, hit, gen, new_counters = program(
-            args, thr, qmask, rmask, rep_arrays, rep_life, sh_arrays, sh_life,
-            np.float32(StoreBank.rel_now()), counters, ticks, shard_ok,
-        )
-        if touch:
-            rep_c, sh_c = new_counters
-            if rb is not None:
-                rb.adopt_fused_counters(*rep_c)
-            for store, (last, cnt) in zip(self.sh_stores, sh_c):
-                store.bank.adopt_fused_counters(last, cnt)
+            program = _build_sharded_program(
+                forward, self.mesh, self.layout, specs, K, rep_meta, sh_meta,
+                lifecycle, touch,
+            )
+            rep_arrays = (rb.buf, rb.valid) if rb is not None else ()
+            rep_life = (
+                (rb.d_created, rb.d_expires, rb.d_staleness())
+                if (rb is not None and lifecycle) else ()
+            )
+            sh_arrays = tuple((s.bank.buf, s.bank.valid) for s in self.sh_stores)
+            sh_life = tuple(
+                (s.bank.d_created, s.bank.d_expires, s.bank.d_staleness())
+                for s in self.sh_stores
+            ) if lifecycle else ()
+            if touch:
+                ticks = tuple(np.int32(b.next_tick()) for b in banks)
+                counters = (
+                    (rb.d_last_access, rb.d_access_count) if rb is not None else (),
+                    tuple(
+                        (s.bank.d_last_access, s.bank.d_access_count)
+                        for s in self.sh_stores
+                    ),
+                )
+            else:
+                ticks = ()
+                counters = ((), ())
+            if shard_mask is None:
+                shard_ok = np.ones(self.n_shards, bool)
+            else:
+                shard_ok = np.asarray(shard_mask, bool).reshape(self.n_shards)
+                if not shard_ok.any():
+                    raise ValueError("shard_mask marks every shard dead")
+                if not shard_ok.all():
+                    self.degraded_reads += 1
+            self.dispatches += 1
+            q, s, idx, winner, hit, gen, new_counters = program(
+                args, thr, qmask, rmask, rep_arrays, rep_life, sh_arrays, sh_life,
+                np.float32(StoreBank.rel_now()), counters, ticks, shard_ok,
+            )
+            if touch:
+                rep_c, sh_c = new_counters
+                if rb is not None:
+                    rb.adopt_fused_counters(*rep_c)
+                for store, (last, cnt) in zip(self.sh_stores, sh_c):
+                    store.bank.adopt_fused_counters(last, cnt)
         # ONE host fetch for all decision tensors (counters stay on device;
         # vector-ingress callers already hold the embeddings, so the
         # replicated q never crosses back — identity forward means q == v)
-        if vecs is not None:
-            s, idx, winner, hit, gen = jax.device_get((s, idx, winner, hit, gen))
-            q = v
-        else:
-            q, s, idx, winner, hit, gen = jax.device_get(
-                (q, s, idx, winner, hit, gen)
-            )
+        with TraceAnnotation("read.fetch"):
+            if vecs is not None:
+                s, idx, winner, hit, gen = jax.device_get((s, idx, winner, hit, gen))
+                q = v
+            else:
+                q, s, idx, winner, hit, gen = jax.device_get(
+                    (q, s, idx, winner, hit, gen)
+                )
         return ReadDecision(q[:n], s[:n], idx[:n], winner[:n], hit[:n], gen[:n])
 
 

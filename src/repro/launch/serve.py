@@ -250,8 +250,7 @@ def main(argv=None):
           f"llm_calls={s.llm_calls} cost=${s.total_cost_usd:.6f} wall={wall:.1f}s")
     print(f"engine: {engine.metrics}")
     cs = cache.stats
-    print(f"cache: lookups={cs.lookups} generative_hits={cs.generative_hits} "
-          f"embed_time={cs.embed_time_s:.2f}s search_time={cs.search_time_s:.3f}s")
+    print(f"cache: lookups={cs.lookups} generative_hits={cs.generative_hits}")
 
 
 if __name__ == "__main__":

@@ -36,8 +36,10 @@ import queue
 import threading
 import time
 from concurrent.futures import Future
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 
 class ServiceClosed(RuntimeError):
@@ -59,7 +61,6 @@ class CoalescerStats:
     batched_items: int = 0
     rejected: int = 0  # admission rejections (bounded queue)
     expired: int = 0  # deadline expiries resolved without a handler call
-    batch_sizes: List[int] = field(default_factory=list)
 
     @property
     def avg_batch(self) -> float:
@@ -78,6 +79,11 @@ class BatchCoalescer:
                      resolves the futures itself (the CacheService mode)
       on_expired   — ``fn(item, future)`` for deadline-expired items; the
                      default resolves the future with ``DeadlineExceeded``
+      name         — names the collector thread's profiler spans, which tile
+                     its time: ``sched.<name>.empty`` (blocked on an empty
+                     heap), ``sched.<name>.ride`` (holding an open batch for
+                     riders) and ``sched.<name>.handle`` (the handler call,
+                     with the futures it resolves and their callbacks)
     """
 
     def __init__(
@@ -89,6 +95,7 @@ class BatchCoalescer:
         max_queue: int = 1024,
         owns_futures: bool = False,
         on_expired: Optional[Callable[[Any, Future], None]] = None,
+        name: str = "batch",
     ):
         assert max_batch >= 1
         self.handler = handler
@@ -97,6 +104,7 @@ class BatchCoalescer:
         self.max_queue = max_queue
         self.owns_futures = owns_futures
         self.on_expired = on_expired
+        self._spans = tuple(f"sched.{name}.{s}" for s in ("empty", "ride", "handle"))
         self.stats = CoalescerStats()
         # entries: (-priority, deadline_key, seq, item, future) — seq is unique,
         # so comparisons never reach the (unorderable) item
@@ -154,26 +162,30 @@ class BatchCoalescer:
         popped entries — a low-priority item starved by a sustained
         high-priority stream must still resolve typed at its deadline, not
         stall its caller until the queue drains."""
+        empty, ride, _ = self._spans
         with self._cv:
-            while not self._heap:
-                if self._closed:
-                    return [], []
-                self._cv.wait(timeout=0.05)
-            deadline = time.perf_counter() + self.max_wait_s
-            while len(self._heap) < self.max_batch and not self._closed:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                self._cv.wait(timeout=remaining)
-            now = time.perf_counter()
-            expired = [e for e in self._heap if e[1] <= now]
-            if expired:
-                self._heap = [e for e in self._heap if e[1] > now]
-                heapq.heapify(self._heap)
-            batch = [
-                heapq.heappop(self._heap)
-                for _ in range(min(self.max_batch, len(self._heap)))
-            ]
+            if not self._heap:
+                with TraceAnnotation(empty):
+                    while not self._heap:
+                        if self._closed:
+                            return [], []
+                        self._cv.wait(timeout=0.05)
+            with TraceAnnotation(ride):
+                deadline = time.perf_counter() + self.max_wait_s
+                while len(self._heap) < self.max_batch and not self._closed:
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    self._cv.wait(timeout=remaining)
+                now = time.perf_counter()
+                expired = [e for e in self._heap if e[1] <= now]
+                if expired:
+                    self._heap = [e for e in self._heap if e[1] > now]
+                    heapq.heapify(self._heap)
+                batch = [
+                    heapq.heappop(self._heap)
+                    for _ in range(min(self.max_batch, len(self._heap)))
+                ]
             return batch, expired
 
     def _collect(self) -> None:
@@ -198,22 +210,22 @@ class BatchCoalescer:
             futs = [f for _, _, _, _, f in batch]
             self.stats.batches += 1
             self.stats.batched_items += len(batch)
-            self.stats.batch_sizes.append(len(batch))
-            try:
-                if self.owns_futures:
-                    self.handler(items, futs)
-                else:
-                    outs = self.handler(items)
-                    if len(outs) != len(items):
-                        raise RuntimeError(
-                            f"handler returned {len(outs)} results for {len(items)} items"
-                        )
-                    for f, out in zip(futs, outs):
-                        f.set_result(out)
-            except Exception as e:  # noqa: BLE001 — propagate to every unresolved rider
-                for f in futs:
-                    if not f.done():
-                        f.set_exception(e)
+            with TraceAnnotation(self._spans[2]):
+                try:
+                    if self.owns_futures:
+                        self.handler(items, futs)
+                    else:
+                        outs = self.handler(items)
+                        if len(outs) != len(items):
+                            raise RuntimeError(
+                                f"handler returned {len(outs)} results for {len(items)} items"
+                            )
+                        for f, out in zip(futs, outs):
+                            f.set_result(out)
+                except Exception as e:  # noqa: BLE001 — propagate to every unresolved rider
+                    for f in futs:
+                        if not f.done():
+                            f.set_exception(e)
 
     # -- lifecycle ------------------------------------------------------------
 

@@ -59,6 +59,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.client import ClientResult, EnhancedClient, LLMResponse
 from repro.core.request import (
@@ -375,6 +376,7 @@ class CacheService:
             max_queue=0,  # max_inflight already bounds admissions
             owns_futures=True,
             on_expired=self._expire,
+            name="dispatch",
         )
         self._lookup_sched = BatchCoalescer(
             self._run_lookup,
@@ -382,6 +384,7 @@ class CacheService:
             max_wait_ms=self.max_wait_ms,
             max_queue=0,
             owns_futures=True,
+            name="lookup",
         )
 
     def _expire(self, pending: _Pending, fut: Future) -> None:
@@ -399,19 +402,24 @@ class CacheService:
     # -- phase A: batched embed -> search -> decide ------------------------------
 
     def _run_lookup(self, pendings: List[_Pending], futs: List[Future]) -> None:
-        with self._cache_lock:
+        with TraceAnnotation("lookup.lock_wait"):
+            self._cache_lock.acquire()
+        try:
             responses = self._lookup_phase(pendings)
-        for pending, fut, resp in zip(pendings, futs, responses):
-            if resp is not None:  # hit/generative hit: resolve NOW
-                if not fut.done():
-                    fut.set_result(resp)
-            else:  # miss residue: original future rides to the dispatcher
-                self._miss_sched.submit(
-                    pending,
-                    priority=pending.request.priority,
-                    deadline_t=pending.deadline_t,
-                    future=fut,
-                )
+        finally:
+            self._cache_lock.release()
+        with TraceAnnotation("lookup.resolve"):
+            for pending, fut, resp in zip(pendings, futs, responses):
+                if resp is not None:  # hit/generative hit: resolve NOW
+                    if not fut.done():
+                        fut.set_result(resp)
+                else:  # miss residue: original future rides to the dispatcher
+                    self._miss_sched.submit(
+                        pending,
+                        priority=pending.request.priority,
+                        deadline_t=pending.deadline_t,
+                        future=fut,
+                    )
 
     def _lookup_phase(
         self, pendings: List[_Pending]
@@ -456,24 +464,26 @@ class CacheService:
             # signature: embed here (its own forward) and call it compatibly
             vecs = np.asarray(owner.embed_batch(prompts))
             cache_results = target.lookup_batch(prompts, contexts, vecs=vecs)
-        for j, i in enumerate(lk):
-            pendings[i].vec = np.asarray(vecs[j])
-        now = time.perf_counter()
-        for i, cr in zip(lk, cache_results):
-            if not cr.hit:
-                continue
-            p = pendings[i]
-            resp = CacheResponse(
-                cr.response, HIT, True, cr, None, "cache", 0.0, now - p.t_submit, p.rid
-            )
-            with self._lock:
-                self.stats.hits += 1
-            with client._state_lock:
-                client.stats.cache_hits += 1
-                client._results[p.rid] = client._to_client_result(resp)
-                if client.cost_ctl:
-                    client.cost_ctl.record(0.0, True)
-            responses[i] = resp
+        with TraceAnnotation("lookup.respond"):
+            for j, i in enumerate(lk):
+                pendings[i].vec = np.asarray(vecs[j])
+            now = time.perf_counter()
+            for i, cr in zip(lk, cache_results):
+                if not cr.hit:
+                    continue
+                p = pendings[i]
+                resp = CacheResponse(
+                    cr.response, HIT, True, cr, None, "cache", 0.0, now - p.t_submit,
+                    p.rid,
+                )
+                with self._lock:
+                    self.stats.hits += 1
+                with client._state_lock:
+                    client.stats.cache_hits += 1
+                    client._results[p.rid] = client._to_client_result(resp)
+                    if client.cost_ctl:
+                        client.cost_ctl.record(0.0, True)
+                responses[i] = resp
         return responses
 
     # -- phase B: miss dispatch + backfill ---------------------------------------
