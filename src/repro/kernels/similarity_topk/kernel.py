@@ -7,9 +7,17 @@ score tile on the MXU and extracts its top-k by k rounds of masked max
 which Mosaic does not provide). The tiny [nb, Q, k] candidate tensor is
 merged by ops.py.
 
-VMEM budget per step: block_n*D*4 + Q*D*4 + Q*block_n*4 bytes;
-block_n=512, D=1024, Q<=16 => ~2.1 MB + 64 KB + 32 KB — comfortably resident,
-and block_n is a lane-aligned multiple of 128 for the MXU.
+The valid flags arrive lane-dense: f32 [1, N] (single) or [L, 1, N] (lanes),
+one [1, block_n] tile a step, which broadcasts against the [Q, block_n] score
+tile as it stands. A column layout ([N, 1]) would cost 128x its size: the
+TPU's (8, 128) tile pads a trailing dimension of 1 to 128 lanes, so 1M flags
+become a 512 MiB buffer in HBM that the wrapper writes and every step streams
+back (256 KB of padding per 1.5 MB row tile at D=768).
+
+VMEM budget per step: block_n*D*4 + Q*D*4 + Q*block_n*4 + 8*block_n*4 bytes
+(the flag tile's one row pads to 8 sublanes); block_n=512, D=1024, Q<=16 =>
+~2.1 MB + 64 KB + 32 KB + 16 KB — comfortably resident, and block_n is a
+lane-aligned multiple of 128 for the MXU.
 """
 from __future__ import annotations
 
@@ -26,7 +34,7 @@ def _topk_block_kernel(db_ref, valid_ref, q_ref, out_s_ref, out_i_ref, *, k: int
     j = pl.program_id(0)
     db = db_ref[...]  # [block_n, D]
     q = q_ref[...]  # [Q, D]
-    valid = valid_ref[...]  # [block_n, 1] f32 (1.0 = valid)
+    valid = valid_ref[...]  # [1, block_n] f32 (1.0 = valid)
 
     s = jax.lax.dot_general(
         q.astype(jnp.float32),
@@ -35,7 +43,7 @@ def _topk_block_kernel(db_ref, valid_ref, q_ref, out_s_ref, out_i_ref, *, k: int
         precision=jax.lax.Precision.HIGHEST,  # f32 scores: thresholds sit on them
         preferred_element_type=jnp.float32,
     )  # [Q, block_n]
-    s = jnp.where(valid[:, 0][None, :] > 0.5, s, NEG)
+    s = jnp.where(valid > 0.5, s, NEG)  # [1, block_n] row broadcasts over Q
 
     Q = s.shape[0]
     col = jax.lax.broadcasted_iota(jnp.int32, (Q, block_n), 1)
@@ -59,7 +67,7 @@ def _topk_lanes_kernel(db_ref, valid_ref, q_ref, out_s_ref, out_i_ref, *, k: int
     j = pl.program_id(block_axis)  # block within the lane
     db = db_ref[0]  # [block_n, D] (lane-sliced by the BlockSpec)
     q = q_ref[...]  # [Q, D]
-    valid = valid_ref[0]  # [block_n, 1] f32 (1.0 = valid)
+    valid = valid_ref[0]  # [1, block_n] f32 (1.0 = valid)
 
     s = jax.lax.dot_general(
         q.astype(jnp.float32),
@@ -68,7 +76,7 @@ def _topk_lanes_kernel(db_ref, valid_ref, q_ref, out_s_ref, out_i_ref, *, k: int
         precision=jax.lax.Precision.HIGHEST,  # f32 scores: thresholds sit on them
         preferred_element_type=jnp.float32,
     )  # [Q, block_n]
-    s = jnp.where(valid[:, 0][None, :] > 0.5, s, NEG)
+    s = jnp.where(valid > 0.5, s, NEG)  # [1, block_n] row broadcasts over Q
 
     Q = s.shape[0]
     col = jax.lax.broadcasted_iota(jnp.int32, (Q, block_n), 1)
@@ -86,7 +94,7 @@ def _topk_lanes_kernel(db_ref, valid_ref, q_ref, out_s_ref, out_i_ref, *, k: int
 def similarity_topk_lanes_blocks(db, valid_f32, q, *, k: int, interpret: bool,
                                  block_n: int = 512,
                                  grid_order: str = "lanes_outer"):
-    """db [L, N, D], valid_f32 [L, N, 1], q [Q, D] -> per-lane per-block
+    """db [L, N, D], valid_f32 [L, 1, N], q [Q, D] -> per-lane per-block
     candidates (scores [L, nb, Q, k], lane-local idx [L, nb, Q, k]).
 
     ``grid_order`` picks the grid iteration layout: ``lanes_outer`` walks
@@ -108,12 +116,14 @@ def similarity_topk_lanes_blocks(db, valid_f32, q, *, k: int, interpret: bool,
         grid = (L, nb)
         block_axis = 1
         lane_map = lambda l, j: (l, j, 0)  # noqa: E731
+        valid_map = lambda l, j: (l, 0, j)  # noqa: E731
         out_map = lambda l, j: (l, j, 0, 0)  # noqa: E731
         q_map = lambda l, j: (0, 0)  # noqa: E731
     elif grid_order == "blocks_outer":
         grid = (nb, L)
         block_axis = 0
         lane_map = lambda j, l: (l, j, 0)  # noqa: E731
+        valid_map = lambda j, l: (l, 0, j)  # noqa: E731
         out_map = lambda j, l: (l, j, 0, 0)  # noqa: E731
         q_map = lambda j, l: (0, 0)  # noqa: E731
     else:
@@ -127,7 +137,7 @@ def similarity_topk_lanes_blocks(db, valid_f32, q, *, k: int, interpret: bool,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_n, D), lane_map),  # lane tile streams
-            pl.BlockSpec((1, block_n, 1), lane_map),  # validity tile
+            pl.BlockSpec((1, 1, block_n), valid_map),  # validity row, lane-dense
             pl.BlockSpec((Q, D), q_map),  # queries resident
         ],
         out_specs=(
@@ -141,7 +151,8 @@ def similarity_topk_lanes_blocks(db, valid_f32, q, *, k: int, interpret: bool,
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
 def similarity_topk_blocks(db, valid_f32, q, *, k: int, interpret: bool, block_n: int = 512):
-    """Returns per-block candidates (scores [nb, Q, k], idx [nb, Q, k])."""
+    """db [N, D], valid_f32 [1, N], q [Q, D] -> per-block candidates
+    (scores [nb, Q, k], idx [nb, Q, k])."""
     N, D = db.shape
     Q = q.shape[0]
     assert N % block_n == 0, f"N={N} must be a multiple of block_n={block_n}"
@@ -157,7 +168,7 @@ def similarity_topk_blocks(db, valid_f32, q, *, k: int, interpret: bool, block_n
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((block_n, D), lambda j: (j, 0)),  # db tile streams
-            pl.BlockSpec((block_n, 1), lambda j: (j, 0)),  # validity tile
+            pl.BlockSpec((1, block_n), lambda j: (0, j)),  # validity row, lane-dense
             pl.BlockSpec((Q, D), lambda j: (0, 0)),  # queries resident
         ],
         out_specs=(

@@ -19,6 +19,11 @@ unit-normalized cosine rows, then cosine lanes are rescaled by 1/|q| — a
 positive per-query scale, so per-lane rankings (and therefore the top-k
 indices) are exact, and the returned scores are true cosines.
 
+Both wrappers hand the kernel its valid flags as one f32 row per lane
+([1, N], or [L, 1, N]), not as an [N, 1] column: under the TPU's (8, 128)
+tile a trailing 1 pads to 128 lanes, so the column costs 512 bytes of HBM a
+row, written on every read and streamed back beside the rows.
+
 Each wrapper counts its host-level invocations so tests and benchmarks can
 assert dispatch budgets (``dispatch_count`` / ``reset_dispatch_count``).
 """
@@ -136,7 +141,7 @@ def _similarity_topk(db, valid, q, *, k: int, metric: str, block_n: int, interpr
     if pad_n:
         db = jnp.pad(db, ((0, pad_n), (0, 0)))
         valid = jnp.pad(valid, (0, pad_n))
-    valid_f32 = valid.astype(jnp.float32)[:, None]
+    valid_f32 = valid.astype(jnp.float32)[None, :]  # [1, N]: lane-dense (kernel.py)
 
     bs, bi = similarity_topk_blocks(db, valid_f32, q, k=k, block_n=bn, interpret=interpret)
     # merge the [nb, Q, k] candidates: one tiny global top-k
@@ -209,7 +214,7 @@ def _similarity_topk_lanes(db, valid, q, *, k: int, metric: Tuple[str, ...],
     if pad_n:
         db = jnp.pad(db, ((0, 0), (0, pad_n), (0, 0)))
         valid = jnp.pad(valid, ((0, 0), (0, pad_n)))
-    valid_f32 = valid.astype(jnp.float32)[..., None]
+    valid_f32 = valid.astype(jnp.float32)[:, None, :]  # [L, 1, N]: lane-dense (kernel.py)
 
     bs, bi = similarity_topk_lanes_blocks(
         db, valid_f32, q, k=k, block_n=bn, interpret=interpret,

@@ -4,7 +4,8 @@ Nothing runs: each test lowers a program over ``jax.eval_shape`` shapes
 placed on a v5e chip that is described, not attached, and compiles it with
 the TPU compiler, which refuses what the chip would refuse (unaligned kernel
 blocks, too much VMEM, programs that do not fit). Shapes are the published
-widths: the top-k kernel over 65,536 rows at D=768, contriever-msmarco at
+widths: the top-k kernel over 65,536 rows at D=768 (and its valid-flag
+layout at 65,536 and 1,048,576 rows), contriever-msmarco at
 [64, 128] tokens, qwen1.5-0.5b's decode step, and the collective read
 program over a 4-chip cache mesh.
 
@@ -77,6 +78,26 @@ def test_similarity_topk_lanes_compiles(one_chip, q_rows):
         prenormalized=True, grid_order="lanes_outer",
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()  # the kernel, not a fallback
+
+
+@pytest.mark.parametrize("rows", [65536, 1048576])
+def test_similarity_topk_lanes_mask_is_lane_dense(one_chip, rows):
+    """The valid flags reach the kernel as a row, not a padded column: an
+    f32[1, rows, 1] buffer takes 512 bytes a row under the (8, 128) tile
+    (536,967,680 temp bytes at 1M rows), held in HBM and streamed per read."""
+    from repro.kernels.similarity_topk.ops import _similarity_topk_lanes
+
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct((1, rows, D), jnp.float32),
+        jax.ShapeDtypeStruct((1, rows), jnp.bool_),
+        jax.ShapeDtypeStruct((8, D), jnp.float32),
+    ))
+    compiled = _similarity_topk_lanes.lower(
+        *args, k=4, metric=("cosine",), block_n=512, interpret=False,
+        prenormalized=True, grid_order="lanes_outer",
+    ).compile()
+    assert f"f32[1,{rows},1]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * 32
 
 
 def test_contriever_forward_compiles(one_chip):

@@ -5,8 +5,8 @@ import numpy as np
 import pytest
 from _compat import given, settings, st
 
-from repro.kernels.similarity_topk.ops import similarity_topk
-from repro.kernels.similarity_topk.ref import similarity_topk_ref
+from repro.kernels.similarity_topk.ops import similarity_topk, similarity_topk_lanes
+from repro.kernels.similarity_topk.ref import similarity_topk_lanes_ref, similarity_topk_ref
 
 SHAPES = [
     # (N, D, Q, k)
@@ -45,6 +45,33 @@ def test_all_invalid_returns_neg_inf():
     valid = jnp.zeros((256,), bool)
     s, i = similarity_topk(db, valid, q, k=4)
     assert bool(jnp.all(jnp.isinf(s)))
+
+
+@pytest.mark.parametrize("grid_order", ["lanes_outer", "blocks_outer"])
+def test_lanes_mask_at_block_edges_matches_ref_exactly(grid_order):
+    """Two lanes over three 128-row blocks plus a partial one (the pad path):
+    flags flip at block edges and one block is wholly invalid. Its rows
+    would outscore every valid one, so a flag tile read at the wrong offset
+    shows. Integer data keeps every dot exact: scores and indices must
+    equal the oracle's bit for bit."""
+    L, bn, D, Q, k = 2, 128, 32, 4, 6
+    N = 3 * bn + 50
+    rng = np.random.default_rng(7)
+    db = rng.integers(-3, 4, size=(L, N, D)).astype(np.float32)
+    q = rng.integers(1, 4, size=(Q, D)).astype(np.float32)
+    valid = np.ones((L, N), bool)
+    valid[0, bn:2 * bn] = False  # a whole block
+    valid[0, [bn - 1, 2 * bn, 3 * bn - 1]] = False  # the rows beside its edges
+    valid[1, :bn - 1] = False  # only the last row of block 0
+    valid[1, 2 * bn:3 * bn] = False  # lane 1's own invalid block
+    valid[1, 3 * bn] = False  # the first row of the partial block
+    db[~valid] = 9.0  # invalid rows score above any valid one
+    s, i = similarity_topk_lanes(db, valid, q, k=k, metric="dot", block_n=bn,
+                                 interpret=True, grid_order=grid_order)
+    s_ref, i_ref = similarity_topk_lanes_ref(db, valid, q, k, metric="dot")
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(s_ref))
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(i_ref))
+    assert valid[np.arange(L)[None, :, None], np.asarray(i)].all()
 
 
 @settings(max_examples=25, deadline=None)
