@@ -1,11 +1,13 @@
 """Seeded open-loop traffic: one general generator that reads a mix file.
 
 A mix (``bench/traffic/<name>.json``) fixes the sizes and the arrival shape;
-``--seed`` fixes the content and the order. The multiset of sizes and gaps is
-drawn from the mix's own ``master_seed``, so every seed serves the same work
-(the same prompt lengths, output lengths, popularity ranks and arrival gaps)
-in another order and with other words. Runs with different seeds then differ
-no more than two runs of one seed do.
+``--seed`` fixes the content and, unless the mix's ``order`` is ``fixed``, the
+order. The multiset of sizes and gaps is drawn from the mix's own
+``master_seed``, so every seed serves the same work (the same prompt lengths,
+output lengths, popularity ranks and arrival gaps) in another order and with
+other words; with ``"order": "fixed"`` in the same order, so that a cell whose
+latency depends on how its long requests queue (a backend serving one
+generation at a time) does not change its queue from seed to seed.
 
 The popularity and burst arithmetic follows ``repro.gateway.traffic``
 (Zipf weights ``(rank + 1) ** -s``; ON/OFF bursts entered with a fixed
@@ -50,7 +52,7 @@ class Workload:
 def load_mix(path: Path) -> dict:
     with open(path) as f:
         mix = json.load(f)
-    for key in ("rate", "arrivals", "cached_prompts", "zipf_s", "repeat_share",
+    for key in ("arrivals", "cached_prompts", "zipf_s", "repeat_share",
                 "prompt_words", "max_tokens", "answer_words", "master_seed",
                 "warmup_seconds"):
         if key not in mix:
@@ -109,12 +111,18 @@ def _stream(mix, master, rng, vocab, corpus, seconds: float, rate: float,
     novel_len = _lognormal_sizes(master, mix["prompt_words"], n - n_rep)
     max_tok = _lognormal_sizes(master, mix["max_tokens"], n)
     episodes = _gaps(master, mix, n, rate)
-    # order and content: from the run's seed
-    kinds = kinds[rng.permutation(n)]
-    ranks = ranks[rng.permutation(n_rep)]
-    novel_len = novel_len[rng.permutation(len(novel_len))]
-    max_tok = max_tok[rng.permutation(n)]
-    episodes = [episodes[i] for i in rng.permutation(len(episodes))]
+    # order and content: from the run's seed; a mix whose "order" is "fixed"
+    # keeps the master stream's order, so that every seed queues the same
+    # work at the same times and only the content differs
+    if mix.get("order", "seeded") == "seeded":
+        kinds = kinds[rng.permutation(n)]
+        ranks = ranks[rng.permutation(n_rep)]
+        novel_len = novel_len[rng.permutation(len(novel_len))]
+        max_tok = max_tok[rng.permutation(n)]
+        episodes = [episodes[i] for i in rng.permutation(len(episodes))]
+    else:
+        # the kinds interleave evenly (a master-seeded shuffle), not in two runs
+        kinds = kinds[master.permutation(n)]
     gaps = np.array([g for ep in episodes for g in ep][:n])
     # open loop over exactly `seconds`: the last gap ends at the window close
     t = np.concatenate([[0.0], np.cumsum(gaps[:-1])]) * (seconds / gaps.sum())
@@ -137,6 +145,9 @@ def _stream(mix, master, rng, vocab, corpus, seconds: float, rate: float,
 def make_workload(mix: dict, seed: int, seconds: float,
                   rate: Optional[float] = None) -> Workload:
     """The run's inputs from (mix, seed, seconds). Same arguments, same bytes."""
+    if rate is None and "rate" not in mix:
+        raise ValueError("the mix states no rate: sweep its knee on the chip "
+                         "(--rate r1,r2,...) and give it 4/5 of the knee")
     rate = float(mix["rate"] if rate is None else rate)
     master = np.random.default_rng(mix["master_seed"])
     rng = np.random.default_rng([seed, 0x5EED])

@@ -2,7 +2,9 @@
 program; takes the weights that ``weights.py`` made from the seed.
 
 - ``encode``: the encoder's hashed word tokenizer (contriever's input as the
-  system defines it: lowercased words and punctuation, blake2b ids).
+  system defines it: lowercased words and punctuation, blake2b ids);
+  ``backend_ids`` the backend's (whitespace words, blake2b ids, a fixed
+  length). The decoders' references are ``bench/archs/<model_type>.py``.
 - ``bert_embed``: a BERT-style post-LN encoder with mean pooling and L2
   normalization, in float32 at the highest matmul precision, with the
   activation the configuration states (``hidden_act``).
@@ -44,6 +46,20 @@ def encode(text: str, vocab_size: int, max_len: int) -> List[int]:
 
 def n_tokens(text: str, max_len: int = 512) -> int:
     return min(1 + len(_WORD_RE.findall(text)), max_len)
+
+
+BACKEND_PROMPT_TOKENS = 32  # the backend reads a prompt's first 32 words
+BACKEND_PAD = 7  # and pads the front of a shorter one with this id
+
+
+def backend_ids(text: str, vocab_size: int, n: int = BACKEND_PROMPT_TOKENS) -> List[int]:
+    """The backend's prompt ids as the system defines them: the first ``n``
+    whitespace-separated words, each a blake2b id, padded at the front to
+    exactly ``n``."""
+    words = text.split()[:n] or ["empty"]
+    ids = [int.from_bytes(hashlib.blake2b(w.encode(), digest_size=4).digest(), "little")
+           % vocab_size for w in words]
+    return [BACKEND_PAD] * (n - len(ids)) + ids
 
 
 # -- encoder --------------------------------------------------------------------
@@ -126,7 +142,11 @@ def quantize(w, kind: str, axis: int):
         sc = jnp.maximum(amax, 1e-12) / 127.0
         return jnp.clip(jnp.round(w / sc), -127, 127) * sc
     sc = jnp.maximum(amax, 1e-12) / 448.0
-    return (w / sc).astype(jnp.float8_e4m3fn).astype(jnp.float32) * sc
+    # the fp8 array is stored: inside a fusion the TPU compiler may keep the
+    # excess precision of a convert pair and skip the rounding, which left
+    # the decoder's fp8 control as exact as bfloat16 weights on the chip
+    q = jax.lax.optimization_barrier((w / sc).astype(jnp.float8_e4m3fn))
+    return q.astype(jnp.float32) * sc
 
 
 # -- search and decide -----------------------------------------------------------

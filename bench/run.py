@@ -67,7 +67,8 @@ sys.path.insert(1, str(ROOT / "src"))
 
 DRAIN_S = 60.0  # answers due in the window may come this long after it
 READ_SAMPLE = 256  # reads compared with the references
-TRACE_S = 5.0  # seconds of the window a --trace 1 run profiles
+TRACE_S = 5.0  # seconds of the window a --trace 1 run profiles, at the least
+TRACE_REQUESTS = 40  # and as many as the mix's rate needs to bring this many
 _COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
@@ -103,11 +104,18 @@ def metric_names(spec: dict, cell: str, trace: bool) -> List[dict]:
 
 
 def load_reader(root: Path, name: str):
-    """``bench/metrics/<name>.py``: a module with ``UNIT`` and ``read(run)``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
+    """``bench/metrics/<name>.py``: a module with ``UNIT`` and ``read(run)``.
+    A metric split by the end-to-end metric it moves, one entry for each
+    group of cells (``compiles_in_window`` and ``compiles_in_window.gen``),
+    shares one reader: ``<name>`` up to its first dot names the file where
+    no file of the whole name is there."""
+    metrics = root / "bench" / "metrics"
+    path = metrics / f"{name}.py"
+    if not path.exists() and "." in name:
+        path = metrics / f"{name.split('.')[0]}.py"
     if not path.exists():
-        raise BenchError(f"no reader {path}")
-    sp = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+        raise BenchError(f"no reader {metrics / name}.py")
+    sp = importlib.util.spec_from_file_location(f"bench_metric_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(sp)
     sp.loader.exec_module(mod)
     return mod
@@ -143,6 +151,7 @@ class Run:
     trace: object = None  # xtrace.Trace of the traced span, or None
     giveup: float = 0.0  # a failed request's latency ends here
     gc_pauses: list = field(default_factory=list)  # (generation, seconds)
+    shard_rows: list = field(default_factory=list)  # live rows per shard of a sharded L2
 
     def latencies(self, classes) -> List[float]:
         """Seconds from due to resolved for requests answered in one of
@@ -352,9 +361,6 @@ def run(args, root: Path = ROOT, require_tpu: bool = True) -> dict:
     spec, cell, cfg_entry = picked["spec"], picked["cell"], picked["config"]
     cfg = load_json(root / cfg_entry["file"])
     mix = load_json(root / "bench" / "traffic" / f"{cell['traffic']}.json")
-    if mix["repeat_share"] < 1.0:
-        raise BenchError(f"{cell['traffic']}: the harness has no reference for the "
-                         "backend's generations, so it takes no novel prompts")
     metrics = metric_names(spec, cell["name"], bool(args.trace))
     readers = {m["name"]: load_reader(root, m["name"]) for m in metrics}
     peaks = load_json(root / "bench" / "peaks.json")
@@ -383,29 +389,45 @@ def run(args, root: Path = ROOT, require_tpu: bool = True) -> dict:
     jax.monitoring.register_event_duration_secs_listener(
         lambda ev, dur, **_: compile_times.append(time.perf_counter()) if ev == _COMPILE else None)
 
+    import numpy as np
+
     import check
     import deploy
     import loadgen
+    import reference as R
     import weights as W
+    from check import served_ids
     from repro.serving.service import CacheService
 
     rates = [float(r) for r in args.rate.split(",")] if args.rate else [None]
-    wl = loadgen.make_workload(mix, args.seed, args.seconds, rates[-1])
-    enc_w, dec_w = W.make_weights(args.seed, cfg["embedder"], cfg["backend"])
-    stack = deploy.build(cfg)
+    try:
+        wl = loadgen.make_workload(mix, args.seed, args.seconds, rates[-1])
+    except ValueError as e:
+        raise BenchError(f"traffic {cell['traffic']}: {e}") from e
+    try:
+        arch = W.load_arch(root / "bench", cfg["backend"]["model_type"])
+    except FileNotFoundError as e:
+        raise BenchError(str(e)) from e
+    enc_w, dec_w = W.make_weights(args.seed, cfg["embedder"], cfg["backend"], arch)
+    stack = deploy.build(cfg, arch)
     deploy.install_weights(stack, enc_w, dec_w)
     t = time.perf_counter()
     n_fill = deploy.fill(stack, args.seed, wl.corpus, wl.answers)
-    log(f"fill: {stack.cache.store.capacity} rows in {time.perf_counter() - t:.1f} s, "
-        f"{t - T_START:.1f} s after start")
+    log(f"fill: {deploy.filled_store(stack).capacity} rows in "
+        f"{time.perf_counter() - t:.1f} s, {t - T_START:.1f} s after start")
     # the scheduler's own batch limit bounds every batch the window forms
     max_batch = inspect.signature(CacheService).parameters["max_batch"].default
     extra_fill = [int(t.split()[1]) for t in
                   deploy.warm_inserts(stack, args.seed, max_batch)]
     deploy.warm_reads(stack, wl.corpus, max_batch)
-    store = stack.cache.store
+    log(f"warm: inserts and reads done {time.perf_counter() - T_START:.1f} s after start, "
+        f"{len(compile_times)} compiles so far")
+    store = deploy.filled_store(stack)
     answers = dict(zip(wl.corpus, wl.answers))
-    recorder = deploy.ReadRecorder(store).install()
+    levels = deploy.levels_of(stack)
+    recorder = deploy.ReadRecorder(levels).install()
+    if args.trace:
+        recorder.watch_engine(stack.engine)
     service = CacheService(stack.client)
 
     def keep(s):  # what the cache now holds for this prompt
@@ -438,9 +460,11 @@ def run(args, root: Path = ROOT, require_tpu: bool = True) -> dict:
     recorder.on = pauses.on = True
     t0 = time.perf_counter() + 0.05
     setup_s = t0 - T_START + AGE_AT_START
+    n_setup_compiles = len(compile_times)
     if args.trace:
         lead = min(2.0, 0.2 * args.seconds)
-        tracer = TraceThread(t0, lead, min(TRACE_S, 0.5 * args.seconds))
+        span = min(max(TRACE_S, TRACE_REQUESTS / wl.meta["rate"]), 0.5 * args.seconds)
+        tracer = TraceThread(t0, lead, span)
         tracer.start()
     window_backfill: Dict[str, tuple] = {}
 
@@ -459,22 +483,48 @@ def run(args, root: Path = ROOT, require_tpu: bool = True) -> dict:
     compiles = sum(1 for tc in compile_times if t0 <= tc <= time.perf_counter())
     recorder.on = pauses.on = False
     gc.callbacks.remove(pauses)
-    mem = devs[0].memory_stats() or {}
-    peak_bytes = int(mem.get("peak_bytes_in_use", 0))
+    used = devs[:cell["chips"]]
+    peak_bytes = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                     for d in used)
     live_rows = int(len(store))
+    shard_rows = []
+    shard_devices = 0
+    if stack.hierarchy is not None:
+        sh = stack.hierarchy.l2.store
+        shard_rows = [int(x) for x in np.asarray(sh.bank.valid).sum(axis=1)]
+        shard_devices = len({a.device for a in sh.bank.buf.addressable_shards})
     dim = store.dim
     service.close(timeout=30)
     # what the comparison needs from the program's state: the reads joined to
-    # the entries, and the rows of the sampled reads' candidates
+    # the entries, the rows of the sampled reads' candidates, the L1's
+    # entries at each sampled read of a hierarchy, and the engine's logits
+    # for a sample of the window's generations, served again
     calls = recorder.join()
     caps, read_faults = check.match_reads(served, calls)
     sample = pick_sample(caps, served, args.seed)
     cands = {i: caps[i][0].cands[caps[i][1]] for i in sample}
-    rows_back = recorder.rows(slot for cs in cands.values() for _, t, slot in cs
-                              if t is not None)
+    rows_back = [lv.rows(c[2] for per in cands.values() for c in per[li]
+                         if c[1] is not None and c[3])
+                 for li, lv in enumerate(levels)]
+    l1_at = None
+    if len(levels) > 1:
+        l1_at = {i: recorder.present(0, caps[i][0].t0, caps[i][0].t1, caps[i][0].thread)
+                 for i in sample}
+    vocab = cfg["backend"]["vocab_size"]
+    gen_pick = check.pick_generations(served, vocab, args.seed)
+    prompt_ids = {i: R.backend_ids(served[i].prompt, vocab) for i in gen_pick}
+    resent = []
+    if gen_pick:
+        t_re = time.perf_counter()
+        with deploy.EngineLogits(stack.engine) as cap:
+            resent = cap.generate([np.asarray(prompt_ids[i], np.int32) for i in gen_pick],
+                                  [served[i].max_tokens for i in gen_pick])
+        log(f"served again: {len(gen_pick)} generations in "
+            f"{time.perf_counter() - t_re:.1f} s")
     recorder.uninstall()
-    recorder.store = None
-    del service, stack, store, recorder
+    for lv in levels:
+        lv.store = None
+    del service, stack, store, recorder, levels
     gc.collect()
     jax.clear_caches()
 
@@ -489,7 +539,9 @@ def run(args, root: Path = ROOT, require_tpu: bool = True) -> dict:
         files = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"), recursive=True)
         if not files:
             raise BenchError("the profiler wrote no trace")
+        t_tr = time.perf_counter()
         trace = xtrace.load(files[0])
+        log(f"trace: read in {time.perf_counter() - t_tr:.1f} s")
         import shutil
 
         shutil.rmtree(tdir, ignore_errors=True)
@@ -503,29 +555,54 @@ def run(args, root: Path = ROOT, require_tpu: bool = True) -> dict:
     known = check.Known(
         answers, args.seed,
         {p: (read_end.get(p, 0.0), done) for p, done in window_backfill.items()})
-    faults = check.answer_faults(served, known, cfg["backend"]["vocab_size"], caps)
+    faults = check.answer_faults(served, known, vocab, caps, pool=l1_at is not None)
     for f in faults[:5]:
         log(f"answer fault: {f}")
+    if l1_at is not None:  # a hierarchy's warm inserts went to its L1
+        extra_fill = []
     ref = check.Reference(cfg, enc_w, known, n_fill, dim, extra_fill,
-                          [served[i].prompt for i in sample])
+                          [served[i].prompt for i in sample], l1_at)
     numbers = ref.program(caps, sample, cands, rows_back)
+    log(f"reference reads: {time.perf_counter() - t_ref:.1f} s")
     numbers.update(read_faults=float(read_faults), answer_faults=float(len(faults)))
+    if shard_rows:
+        numbers["shard_faults"] = float(cell["chips"] - shard_devices)
+    served_seqs = [(prompt_ids[i], served_ids(served[i].text, vocab)) for i in gen_pick]
+    if gen_pick:
+        # the re-sent generations are compared where they were produced; as a
+        # rule they repeat the served tokens, and one forward judges both
+        same = all(list(t) == list(r[0]) for (_, t), r in zip(served_seqs, resent))
+        seqs = [(p, t, r[1] if same else None) for (p, t), r in zip(served_seqs, resent)]
+        numbers["gen_gap"], err = check.gen_numbers(arch, dec_w, cfg["backend"], seqs)
+        if not same:
+            _, err = check.gen_numbers(arch, dec_w, cfg["backend"],
+                                       [(p, r[0], r[1]) for (p, _), r in
+                                        zip(served_seqs, resent)])
+        numbers["logit_err"] = err
+        numbers["resent_differs"] = float(not same)
     limits = cfg["limits"]
     judged = numbers
     if args.control:
-        judged = dict(numbers, **ref.control(caps, sample))
+        ctl = ref.control(caps, sample)
+        if gen_pick:
+            ctl["gen_gap"], ctl["logit_err"] = check.gen_numbers(
+                arch, dec_w, cfg["backend"], [(p, t, None) for p, t in served_seqs],
+                quant=cfg["control"]["backend"])
+        judged = dict(numbers, **ctl)
         log("program: " + " ".join(f"{k}={v:.6g}" for k, v in numbers.items()))
     ref_s = time.perf_counter() - t_ref
     checks = {k: {"value": v, "limit": limits[k]} for k, v in judged.items() if k in limits}
     correct = bool(caps) and all(c["value"] <= c["limit"] for c in checks.values())
-    log(f"reference: {len(sample)} reads, {ref_s:.1f} s")
+    log(f"reference: {len(sample)} reads, {len(gen_pick)} generations, {ref_s:.1f} s; "
+        f"set-up {setup_s:.1f} s with {n_setup_compiles} compiles")
 
     # -- metrics ------------------------------------------------------------
     for s in served:
         if s.status is None and s.error is None:
             s.error = "unresolved"
     record = Run(cfg, mix, peaks[kind], t0, args.seconds, setup_s, served, c0, c1,
-                 compiles, live_rows, dim, calls, trace, giveup, pauses.pauses)
+                 compiles, live_rows, dim, calls, trace, giveup, pauses.pauses,
+                 shard_rows)
     out_metrics = {}
     for m in metrics:
         v = readers[m["name"]].read(record)

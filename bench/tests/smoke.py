@@ -18,26 +18,32 @@ sys.path.insert(0, str(BENCH))
 sys.path.insert(1, str(BENCH.parent / "src"))
 
 CELL = "tiny.hits"
-CHIP_CONFIG = BENCH / "configs" / "ctr-qwen05b-1m.json"
+CONFIGS = BENCH / "configs"
 
 
 def make_root(tmp: Path) -> Path:
-    """A checkout-like tree: the fixture's BENCHMARK.json, config and mix, and
-    the benchmark's own metric readers and peak table (with the CPU in it).
-    The config takes the chip configuration's precision, control and limits,
-    so the tests judge by the same numbers as the chip."""
+    """A checkout-like tree: the fixture's BENCHMARK.json, configs and mixes,
+    and the benchmark's own decoders, metric readers and peak table (with the
+    CPU in it). Each config takes the precision, control and limits of the
+    chip configuration it names (``chip_config``), so the tests judge by
+    the same numbers as the chip."""
     root = tmp / "root"
     if root.exists():  # made by an earlier run: keep its compile cache
         return root
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir(parents=True)
     shutil.copytree(BENCH / "metrics", root / "bench" / "metrics")
+    shutil.copytree(BENCH / "archs", root / "bench" / "archs",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(FIX / "BENCHMARK.json", root / "BENCHMARK.json")
-    cfg = json.loads((FIX / "tiny-ctr-qwen.json").read_text())
-    chip = json.loads(CHIP_CONFIG.read_text())
-    cfg.update({k: chip[k] for k in ("precision", "control", "limits")})
-    (root / "bench" / "configs" / "tiny-ctr-qwen.json").write_text(json.dumps(cfg))
-    shutil.copy(FIX / "tiny.hits.json", root / "bench" / "traffic" / "tiny.hits.json")
+    spec = json.loads((FIX / "BENCHMARK.json").read_text())
+    for c in spec["configs"]:
+        cfg = json.loads((FIX / Path(c["file"]).name).read_text())
+        chip = json.loads((CONFIGS / f"{cfg['chip_config']}.json").read_text())
+        cfg.update({k: chip[k] for k in ("precision", "control", "limits")})
+        (root / c["file"]).write_text(json.dumps(cfg))
+    for w in spec["workloads"]:
+        shutil.copy(FIX / f"{w['traffic']}.json", root / "bench" / "traffic")
     peaks = json.loads((BENCH / "peaks.json").read_text())
     import jax
 
@@ -64,11 +70,11 @@ def small_program(monkeypatch) -> None:
 
 
 def run_tiny(tmp: Path, monkeypatch, seed: int = 7, seconds: float = 2.0, trace: int = 0,
-             control: int = 0) -> dict:
+             control: int = 0, cell: str = CELL) -> dict:
     import run as harness
 
     small_program(monkeypatch)
     root = make_root(tmp)
-    args = argparse.Namespace(workload=CELL, seed=seed, seconds=seconds, trace=trace,
+    args = argparse.Namespace(workload=cell, seed=seed, seconds=seconds, trace=trace,
                               rate=None, control=control)
     return harness.run(args, root=root, require_tpu=False)

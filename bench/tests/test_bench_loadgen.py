@@ -55,3 +55,31 @@ def test_window_is_open_loop_over_the_seconds():
     assert len(set(novel)) == len(novel)
     assert not set(novel) & set(wl.corpus)
     assert all(r.prompt == wl.corpus[r.rank] for r in wl.window if r.kind == "repeat")
+
+
+def test_fixed_order_queues_the_same_work_for_every_seed():
+    """With ``"order": "fixed"`` two seeds send the same kinds, lengths and
+    output lengths at the same times; only the words differ."""
+    mix = dict(_mix(), order="fixed")
+    a = loadgen.make_workload(mix, 2**31 + 1, 10.0)
+    b = loadgen.make_workload(mix, 2**31 + 2, 10.0)
+    shape = lambda w: [(r.t_due, r.kind, r.max_tokens, r.rank, len(r.prompt.split()))  # noqa: E731
+                       for r in w.window]
+    assert shape(a) == shape(b)
+    assert [r.prompt for r in a.window] != [r.prompt for r in b.window]
+    kinds = [r.kind for r in a.window]
+    assert kinds != sorted(kinds)  # repeats and novel prompts interleave
+    seeded = loadgen.make_workload(dict(mix, order="seeded"), 2**31 + 2, 10.0)
+    assert shape(seeded) != shape(b)
+
+
+def test_a_mix_without_a_rate_needs_one_given():
+    """A mix whose knee has not been swept states no rate: it runs only at a
+    rate given on the command line (a sweep), never at a guessed one."""
+    import pytest
+
+    m = _mix()
+    del m["rate"]
+    with pytest.raises(ValueError, match="no rate"):
+        loadgen.make_workload(m, 3, 5.0)
+    assert len(loadgen.make_workload(m, 3, 5.0, rate=4.0).window) == 20

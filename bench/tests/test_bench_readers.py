@@ -13,7 +13,7 @@ import flops
 import run as harness
 import xtrace
 from check import Served
-from deploy import Capture, ReadRecorder
+from deploy import Capture, Level, ReadRecorder
 from readings import percentile
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -148,27 +148,43 @@ class _Decision:
         self.idx = np.asarray(idx)[:, None]
         self.vecs = np.zeros((len(scores), 2), np.float32)
         self.hit = self.generative = np.zeros((len(scores), 1), bool)
+        self.winner = np.ones((len(scores),), np.int32)
 
 
 def test_join_leaves_out_slots_written_since_the_read(monkeypatch):
+    """A slot written again after the read keeps the entry it held at the
+    read, and is left out of the rows read back; a slot that another thread
+    wrote just before the read is not known."""
+    import threading
+
     import repro.core.read_path as read_path
 
     store = _Store(8)
-    dec = _Decision([[0.9, 0.8, -np.inf]], [[1, 2, 3]])
+    dec = _Decision([[0.9, 0.8, 0.7, -np.inf]], [[1, 2, 4, 3]])
     monkeypatch.setattr(read_path, "fused_read", lambda *a, **k: dec)
-    rec = ReadRecorder(store).install()
+    lv = Level("L1", store, False)
+    rec = ReadRecorder([lv]).install()
     try:
         rec.on = True
+        other = threading.Thread(target=lambda: (
+            store._entries.__setitem__(4, _Entry("racing")),
+            store._bank.note_insert(0, 4)))
+        other.start()
+        other.join(timeout=10)
         assert read_path.fused_read(None, None, ["a"], None, None) is dec
-        store._bank.note_insert(0, 2)  # slot 2 rewritten after the read
-        store._entries[2] = _Entry("later")
+        store._entries[2] = _Entry("later")  # slot 2 rewritten after the read
+        store._bank.note_insert(0, 2)
     finally:
         rec.uninstall()
     assert "note_insert" not in vars(store._bank)
     (c,) = rec.join()
-    assert c.texts == ["a"]
-    # the slot written since the read keeps its score and slot, not a prompt;
-    # the empty candidate (-inf) is left out
-    assert c.cands == [[(pytest.approx(0.9), "q1", 1), (pytest.approx(0.8), None, 2)]]
-    got = rec.rows([2, 1, 2])
+    assert c.texts == ["a"] and c.cls == ["miss"] and c.level == [1]
+    # slot 2 held q2 at the read, but its row is no longer the one read; the
+    # empty candidate (-inf) is left out
+    assert c.cands == [[[(pytest.approx(0.9), "q1", 1, True),
+                         (pytest.approx(0.8), "q2", 2, False),
+                         (pytest.approx(0.7), None, 4, False)]]]
+    assert rec.present(0, c.t0, c.t1, c.thread) == (
+        [f"q{i}" for i in range(8) if i != 4], False)
+    got = lv.rows([2, 1, 2])
     assert sorted(got) == [1, 2] and got[2].tolist() == [4.0, 5.0]

@@ -95,6 +95,45 @@ def test_broken_timed_path_is_not_correct(shared, monkeypatch, fault, number):
     assert number in _failed(res["checks"]), res["checks"]
 
 
+def test_mixed_sound_run_is_correct(shared, monkeypatch):
+    """Misses reach the backend: their generations are judged against the
+    decoder's plain reference, and the rate of their tokens is reported."""
+    res = smoke.run_tiny(shared, monkeypatch, seed=2**31 + 12, seconds=3.0,
+                         cell="tiny.mixed")
+    assert res["correct"], res["checks"]
+    assert set(res["metrics"]) == {"gen_tokens_per_s", "setup_s"}
+    assert {"gen_gap", "logit_err"} <= set(res["checks"])
+
+
+def test_mixed_control_is_not_correct(shared, monkeypatch):
+    res = smoke.run_tiny(shared, monkeypatch, seed=2**31 + 12, seconds=3.0,
+                         cell="tiny.mixed", control=1)
+    assert res["control"]["backend"] == "fp8"
+    assert not res["correct"]
+    assert _failed(res["checks"]), res["checks"]
+
+
+def test_altered_token_is_not_correct(shared, monkeypatch):
+    """Every generation's first token, altered where the engine produces it,
+    lies far below the reference's best."""
+    from repro.serving.engine import ServingEngine
+
+    orig = ServingEngine._admit
+
+    def altered(self):
+        before = set(self.active)
+        orig(self)
+        for rid, req in self.active.items():
+            if rid not in before:
+                req.out_tokens[0] = (req.out_tokens[0] + 1) % self.cfg.vocab_size
+
+    monkeypatch.setattr(ServingEngine, "_admit", altered)
+    res = smoke.run_tiny(shared, monkeypatch, seed=2**31 + 13, seconds=3.0,
+                         cell="tiny.mixed")
+    assert not res["correct"]
+    assert "gen_gap" in _failed(res["checks"]), res["checks"]
+
+
 def test_no_chip_no_result(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(

@@ -3,11 +3,14 @@
 The served stack takes these in place of the ones it builds for itself, so
 the plain references in ``reference.py`` can run on the same numbers without
 taking anything the program made. Both trees use the program's parameter
-layout (``repro.models.transformer`` for the decoder, ``repro.core.
-embeddings`` for the encoder); ``deploy.install_weights`` checks every leaf's
+layout (``repro.core.embeddings`` for the encoder, here; the decoder's
+comes from its file under ``bench/archs/``); ``deploy.install_weights`` checks every leaf's
 path, shape and dtype against the program's own before it swaps them in.
 """
 from __future__ import annotations
+
+import importlib.util
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -53,45 +56,25 @@ def encoder_tree(key, enc: dict) -> dict:
     return tree
 
 
-def decoder_tree(key, dec: dict) -> dict:
-    """Qwen-style decoder (qwen1.5): bf16, layer-stacked, tied embeddings,
-    QKV bias."""
-    d, F, V, n = (dec["hidden_size"], dec["intermediate_size"],
-                  dec["vocab_size"], dec["num_hidden_layers"])
-    H, K = dec["num_attention_heads"], dec["num_key_value_heads"]
-    Dh = d // H
-    bf = jnp.bfloat16
-    ks = iter(jax.random.split(key, 16))
-    return {
-        "embed": {"table": _normal(next(ks), (V, d), d ** -0.5, bf)},
-        "final_norm": jnp.ones((d,), bf),
-        "layers": {
-            "ln1": jnp.ones((n, d), bf),
-            "ln2": jnp.ones((n, d), bf),
-            "attn": {
-                "wq": _normal(next(ks), (n, d, H, Dh), d ** -0.5, bf),
-                "wk": _normal(next(ks), (n, d, K, Dh), d ** -0.5, bf),
-                "wv": _normal(next(ks), (n, d, K, Dh), d ** -0.5, bf),
-                "wo": _normal(next(ks), (n, H, Dh, d), (H * Dh) ** -0.5, bf),
-                "bq": _normal(next(ks), (n, H, Dh), 0.02, bf),
-                "bk": _normal(next(ks), (n, K, Dh), 0.02, bf),
-                "bv": _normal(next(ks), (n, K, Dh), 0.02, bf),
-            },
-            "ffn": {
-                "wi_gate": _normal(next(ks), (n, d, F), d ** -0.5, bf),
-                "wi_up": _normal(next(ks), (n, d, F), d ** -0.5, bf),
-                "wo": _normal(next(ks), (n, F, d), F ** -0.5, bf),
-            },
-        },
-    }
+def load_arch(bench: Path, model_type: str):
+    """``bench/archs/<model_type>.py``: the decoder the configuration's
+    ``backend.model_type`` names (the published ``config.json``'s own key)."""
+    path = bench / "archs" / f"{model_type}.py"
+    if not path.exists():
+        raise FileNotFoundError(f"no decoder {path} for model_type {model_type!r}")
+    sp = importlib.util.spec_from_file_location(f"bench_arch_{model_type}", path)
+    mod = importlib.util.module_from_spec(sp)
+    sp.loader.exec_module(mod)
+    return mod
 
 
-def make_weights(seed: int, enc: dict, dec: dict):
-    """(encoder, decoder) weight trees, generated on the device."""
+def make_weights(seed: int, enc: dict, dec: dict, arch):
+    """(encoder, decoder) weight trees, generated on the device in one call;
+    ``arch`` (``load_arch``) makes the decoder's."""
     @jax.jit
     def gen(key):
         ke, kd = jax.random.split(key)
-        return encoder_tree(ke, enc), decoder_tree(kd, dec)
+        return encoder_tree(ke, enc), arch.weights(kd, dec)
 
     return gen(seed_key(seed))
 

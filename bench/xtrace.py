@@ -9,6 +9,7 @@ before it stops.
 """
 from __future__ import annotations
 
+import bisect
 import json
 import re
 from dataclasses import asdict, dataclass, field
@@ -16,6 +17,7 @@ from typing import Dict, List, Optional, Tuple
 
 WINDOW = "bench_trace_window"
 READ = "bench_read#"  # + the read's sequence number; the harness's own span
+DECODE = "bench_decode#"  # + the step's number; the harness's span around a decode step
 
 
 @dataclass
@@ -30,11 +32,23 @@ class Ev:
 
 
 @dataclass
+class Device:
+    name: str
+    modules: List[Ev] = field(default_factory=list)  # XLA modules
+    ops: List[Ev] = field(default_factory=list)  # XLA ops
+
+
+@dataclass
 class Trace:
     modules: List[Ev] = field(default_factory=list)  # device 0: XLA modules
     ops: List[Ev] = field(default_factory=list)  # device 0: XLA ops
     host: Dict[str, List[Ev]] = field(default_factory=dict)  # thread -> spans
     device: str = ""
+    others: List[Device] = field(default_factory=list)  # devices after the first
+
+    def devices(self) -> List[Device]:
+        """Every traced device, the first one first."""
+        return [Device(self.device, self.modules, self.ops)] + self.others
 
     def window(self) -> Tuple[float, float]:
         for evs in self.host.values():
@@ -49,34 +63,41 @@ class Trace:
             "modules": [asdict(e) for e in self.modules],
             "ops": [asdict(e) for e in self.ops],
             "host": {k: [asdict(e) for e in v] for k, v in self.host.items()},
+            "others": [asdict(d) for d in self.others],
         })
 
     @staticmethod
     def from_json(s: str) -> "Trace":
         d = json.loads(s)
-        return Trace([Ev(**e) for e in d["modules"]], [Ev(**e) for e in d["ops"]],
-                     {k: [Ev(**e) for e in v] for k, v in d["host"].items()},
-                     d["device"])
+        evs = lambda xs: [Ev(**e) for e in xs]  # noqa: E731
+        return Trace(evs(d["modules"]), evs(d["ops"]),
+                     {k: evs(v) for k, v in d["host"].items()}, d["device"],
+                     [Device(o["name"], evs(o["modules"]), evs(o["ops"]))
+                      for o in d.get("others", [])])
 
 
 def load(path: str, device_prefix: str = "/device:TPU:") -> Trace:
-    """Events of the first device plane whose name starts with
-    ``device_prefix``, and of every host thread."""
+    """Events of every device plane whose name starts with ``device_prefix``
+    (the first in ``modules`` and ``ops``, the others in ``others``), and of
+    every host thread."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
     tr = Trace()
     dev_planes = sorted((p for p in pd.planes if p.name.startswith(device_prefix)),
-                        key=lambda p: p.name)
-    if dev_planes:
-        p = dev_planes[0]
-        tr.device = p.name
+                        key=lambda p: int(re.sub(r"\D", "", p.name[len(device_prefix):]) or 0))
+    for k, p in enumerate(dev_planes):
+        dev = Device(p.name)
         for line in p.lines:
             evs = [Ev(e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9) for e in line.events]
             if line.name == "XLA Modules":
-                tr.modules += evs
+                dev.modules += evs
             elif line.name == "XLA Ops":
-                tr.ops += evs
+                dev.ops += evs
+        if k == 0:
+            tr.device, tr.modules, tr.ops = dev.name, dev.modules, dev.ops
+        else:
+            tr.others.append(dev)
     for p in pd.planes:
         if p.name.startswith("/host:CPU"):
             for line in p.lines:
@@ -91,10 +112,15 @@ def _clip(evs: List[Ev], t0: float, t1: float) -> List[Tuple[float, float]]:
 
 
 def busy(tr: Trace) -> float:
-    """Seconds of the window in which some op ran on the device."""
-    t0, t1 = tr.window()
+    """Seconds of the window in which some op ran on a device, averaged over
+    the traced devices."""
+    devs = tr.devices()
+    return sum(_busy(d.ops or d.modules, *tr.window()) for d in devs) / len(devs)
+
+
+def _busy(evs: List[Ev], t0: float, t1: float) -> float:
     total, cur_s, cur_e = 0.0, None, None
-    for s, e in _clip(tr.ops or tr.modules, t0, t1):
+    for s, e in _clip(evs, t0, t1):
         if cur_e is None or s > cur_e:
             if cur_e is not None:
                 total += cur_e - cur_s
@@ -177,7 +203,7 @@ def host_at(tr: Trace, t: float) -> str:
     for thread, evs in tr.host.items():
         inner = None
         for e in evs:
-            if e.name == WINDOW or e.name.startswith(READ):
+            if e.name == WINDOW or e.name.startswith((READ, DECODE)):
                 continue
             if e.start <= t <= e.end and (inner is None or e.dur < inner.dur):
                 inner = e
@@ -199,3 +225,27 @@ def reads_in_window(tr: Trace) -> List[int]:
             if e.name.startswith(READ) and e.start >= t0 and e.end <= t1:
                 out.append(int(e.name[len(READ):]))
     return sorted(out)
+
+
+def executions(tr: Trace, pattern: str) -> List[Tuple[Device, List[Ev]]]:
+    """Per traced device, the executions inside the window of the XLA
+    modules whose name matches ``pattern``; devices with none are left
+    out."""
+    out = []
+    for d in tr.devices():
+        evs = matching(d.modules, pattern, tr)
+        if evs:
+            out.append((d, evs))
+    return out
+
+
+def ops_within(ops: List[Ev], mods: List[Ev]) -> List[Tuple[Ev, float]]:
+    """The ops (with their self times) that ran inside one of ``mods``."""
+    mods = sorted(mods, key=lambda e: e.start)
+    starts = [m.start for m in mods]
+    out = []
+    for e, t in self_times(ops):
+        i = bisect.bisect_right(starts, e.start) - 1
+        if i >= 0 and e.end <= mods[i].end + 1e-9:
+            out.append((e, t))
+    return out
